@@ -2,7 +2,9 @@
 
 (1) Gradient DP all-reduce: dense psum vs Seeker top-k coreset payload —
     wire bytes from the lowered HLO of both train steps on an 8-way DP mesh
-    (subprocess; this process stays single-device).
+    of virtual CPU devices, in a child process held to the CPU
+    (``JAX_PLATFORMS=cpu``): it only lowers HLO, so it never competes with
+    this process for an accelerator.  A failed child fails the benchmark.
 (2) Edge->host activation offload: raw windows vs quantized cluster-coreset
     payload bytes through collective_permute (analytic + codec roundtrip).
 """
@@ -64,13 +66,14 @@ print(json.dumps({"dense": a.collective_bytes, "comp": b.collective_bytes,
 
 def _grad_rows() -> list[dict]:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(_SUBPROC)],
                          capture_output=True, text=True, timeout=560, env=env)
     if out.returncode != 0:
-        return [{"name": "comm/grad_compression_ERROR", "us_per_call": 0.0,
-                 "error": out.stderr[-400:]}]
+        raise RuntimeError(f"gradient-compression HLO child failed "
+                           f"(exit {out.returncode}): {out.stderr[-2000:]}")
     d = json.loads(out.stdout.strip().splitlines()[-1])
     return [
         {"name": "comm/grad_dense_psum", "us_per_call": 0.0,

@@ -72,6 +72,8 @@ def main() -> None:
                          "(one span per benchmark module)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.obs import compile_counts, trace as obs_trace
     if args.trace_out:
         obs_trace.enable()

@@ -331,6 +331,8 @@ def main():
                     help="record the run as Chrome-trace/Perfetto JSON")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.obs import trace as obs_trace
     if args.trace_out:
         obs_trace.enable()

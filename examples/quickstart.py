@@ -47,7 +47,7 @@ def main():
     print(f"memoization: hit={bool(memo.hit)} label={int(memo.label)} "
           f"corr={float(memo.max_corr):.3f}")
 
-    # --- the Pallas kernels (paper's coreset engine, interpret mode) --------
+    # --- the kernel ops (Pallas on a TPU, the jnp oracle elsewhere) --------
     pts = jnp.stack([jnp.linspace(0, 1, 60)[:, None] * 4.0,
                      window[:, :1]], axis=-1).reshape(1, 60, 2)
     centers, radii, counts = kmeans_coreset_op(pts, k=12)

@@ -8,7 +8,8 @@ with the scale precomputed per tensor (or per output channel).
 The kernel is deliberately trivial compute — its value is *fusion*: one HBM
 round-trip instead of the 4 ops XLA would otherwise materialize, and it is
 the template every quantized layer in the serving path reuses.  Tiles are
-(block_r, block_c) with the last dim 128-aligned by the wrapper.
+(block_r, block_c): the wrapper pads to 256-row x 512-column tiles, or uses
+the whole dim where it is smaller, as Mosaic's block rules allow.
 """
 from __future__ import annotations
 
@@ -17,6 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import default_interpret
 
 __all__ = ["fake_quant_pallas"]
 
@@ -33,15 +36,17 @@ def _quant_kernel(x_ref, scale_ref, out_ref, *, qmax: float):
                                              "block_c", "interpret"))
 def fake_quant_pallas(x2d: jnp.ndarray, bits: int, per_channel: bool = False,
                       block_r: int = 256, block_c: int = 512,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     """Fake-quantize a 2-D tensor (rows, channels). Wrapper pads/reshapes.
 
     Args:
         x2d: (R, C) float tensor, R % block_r == 0, C % block_c == 0.
         bits: precision (paper: 16 and 12; 8 for the ablation of Fig. 2c).
         per_channel: scale per last-dim channel instead of per tensor.
+        interpret: ``None`` resolves per backend.
     """
     r, c = x2d.shape
+    interpret = default_interpret() if interpret is None else interpret
     block_r = min(block_r, r)
     block_c = min(block_c, c)
     assert r % block_r == 0 and c % block_c == 0

@@ -7,10 +7,12 @@ the m most important samples.
 
 TPU adaptation: the MCU engine iterates ≤7 times serially; here one program
 holds a (BB, T, C) window block in VMEM, computes the box-filtered deviation
-with T-length shifted adds (static unroll of the 8-tap box), and runs an
-m-step argmax/mask selection loop entirely in registers/VMEM.  Selection is
-returned *sorted by time index* so downstream payload encoding is monotone —
-sorting m≤32 keys uses a static insertion network over the carried arrays.
+with T-length shifted adds (static slices of the edge-padded block), and runs
+an m-step argmax/mask selection loop that marks the picks in a (BB, T) mask.
+The mask is already in time order, so the selection comes out *sorted by
+time index* (downstream payload encoding is monotone) without a sort: each
+pick's slot is its running rank among the picks, a triangular matmul, and
+the (BB, m, T) one-hot built from it gathers values and weights on the MXU.
 """
 from __future__ import annotations
 
@@ -20,7 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import default_interpret
+
 __all__ = ["importance_select_pallas"]
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _moving_average(x: jnp.ndarray, width: int) -> jnp.ndarray:
@@ -33,7 +39,7 @@ def _moving_average(x: jnp.ndarray, width: int) -> jnp.ndarray:
     t = x.shape[1]
     acc = jnp.zeros_like(x)
     for j in range(width):                                   # static unroll
-        acc = acc + jax.lax.dynamic_slice_in_dim(xp, j, t, axis=1)
+        acc = acc + xp[:, j:j + t, :]
     return acc / width
 
 
@@ -47,22 +53,30 @@ def _select_kernel(windows_ref, idx_ref, vals_ref, weights_ref, *,
     w = detr / jnp.maximum(jnp.sum(detr, axis=-1, keepdims=True), 1e-9)
     w = (1.0 - spread) * w + spread / t                     # (BB, T)
 
-    def pick(i, carry):
-        masked, sel = carry
+    time = jax.lax.broadcasted_iota(jnp.int32, (bb, t), 1)
+
+    def pick(_, carry):
+        masked, chosen = carry
         best = jnp.argmax(masked, axis=-1)                  # (BB,)
-        sel = sel.at[:, i].set(best.astype(jnp.int32))
-        masked = masked * (jnp.arange(t)[None, :] != best[:, None])
-        return masked, sel
+        hit = time == best[:, None]
+        return jnp.where(hit, 0.0, masked), jnp.where(hit, 1.0, chosen)
 
-    sel0 = jnp.zeros((bb, m), jnp.int32)
-    _, sel = jax.lax.fori_loop(0, m, pick, (w, sel0))
+    _, chosen = jax.lax.fori_loop(0, m, pick, (w, jnp.zeros_like(w)))
 
-    sel = jnp.sort(sel, axis=-1)                            # ascending time order
-    onehot = (sel[..., None] == jnp.arange(t)[None, None, :]).astype(jnp.float32)
-    vals = jnp.einsum("bmt,btc->bmc", onehot, x,
+    # rank[b, t] = picks strictly before t: a 0/1 triangular matmul (exact)
+    before = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+              < jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+              ).astype(jnp.float32)
+    rank = jnp.dot(chosen, before, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)      # (BB, T)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bb, m, t), 1).astype(jnp.float32)
+    onehot = ((rank[:, None, :] == slot) & (chosen[:, None, :] > 0)
+              ).astype(jnp.float32)                         # (BB, m, T)
+    times = jax.lax.broadcasted_iota(jnp.int32, (bb, m, t), 2).astype(jnp.float32)
+    sel = jnp.sum(onehot * times, axis=-1).astype(jnp.int32)  # ascending
+    vals = jnp.einsum("bmt,btc->bmc", onehot, x, precision=_HIGHEST,
                       preferred_element_type=jnp.float32)   # gather via matmul
-    sel_w = jnp.einsum("bmt,bt->bm", onehot, w,
-                       preferred_element_type=jnp.float32)
+    sel_w = jnp.sum(onehot * w[:, None, :], axis=-1)        # (BB, m)
     weights = 1.0 / jnp.maximum(m * sel_w, 1e-9)
 
     idx_ref[...] = sel
@@ -75,18 +89,20 @@ def _select_kernel(windows_ref, idx_ref, vals_ref, weights_ref, *,
                                     "interpret"))
 def importance_select_pallas(windows: jnp.ndarray, m: int, spread: float = 0.25,
                              avg_width: int = 8, block_b: int = 8,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Deterministic top-m importance selection over a window batch.
 
     Args:
         windows: (B, T, C) float windows; B % block_b == 0.
         m: samples to keep (paper: 20 for HAR).
+        interpret: ``None`` resolves per backend.
 
     Returns (indices (B,m) i32 ascending, values (B,m,C) f32,
              HT-weights (B,m) f32).
     """
     b, t, c = windows.shape
     assert b % block_b == 0
+    interpret = default_interpret() if interpret is None else interpret
     grid = (b // block_b,)
     return pl.pallas_call(
         functools.partial(_select_kernel, m=m, spread=spread,

@@ -1,25 +1,25 @@
 """Public jit'd wrappers around the Pallas kernels, with backend dispatch.
 
-Handles padding to block multiples, dtype coercion, interpret-mode selection
-(``interpret=True`` everywhere except a real TPU backend), and un-padding of
-the results.  Call these, not the kernels, from library code.
+Handles padding to block multiples, dtype coercion and un-padding of the
+results.  Call these, not the kernels, from library code.
 
-Dispatch: every op takes ``impl`` — ``"pallas"`` runs the Pallas kernel
-(interpret mode off-TPU), ``"ref"`` runs the pure-jnp oracle from
-:mod:`repro.kernels.ref`.  The default (``None``) resolves to ``"pallas"``
-on a real TPU backend and ``"ref"`` elsewhere: the oracles are validated
-bit-for-tolerance against the kernels (tests/test_kernels.py), compile to
-plain XLA on CPU/GPU, and — unlike interpret-mode Pallas — stay fast under
-``vmap``/``scan``, which is what the fleet engine's hot path needs.
+Dispatch: every op takes ``impl`` — ``"pallas"`` runs the Pallas kernel,
+``"ref"`` runs the pure-jnp oracle from :mod:`repro.kernels.ref`.  The
+default (``None``) resolves to ``"pallas"`` on a TPU backend, where the
+kernels compile with Mosaic, and to ``"ref"`` elsewhere: the oracles are
+validated bit-for-tolerance against the kernels (tests/test_kernels.py),
+compile to plain XLA on CPU/GPU, and — unlike interpret-mode Pallas — stay
+fast under ``vmap``/``scan``, which is what the fleet engine's hot path
+needs.  ``interpret`` (default ``None``) likewise resolves per backend:
+compiled on a TPU, Python-evaluated elsewhere (how the CPU tests pin the
+kernel bodies to the oracles).
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from . import ref
+from .backend import default_impl, default_interpret
 from .fake_quant import fake_quant_pallas
 from .importance_select import importance_select_pallas
 from .kmeans_coreset import kmeans_coreset_pallas
@@ -27,16 +27,6 @@ from .signature_corr import signature_corr_pallas
 
 __all__ = ["kmeans_coreset_op", "importance_select_op", "signature_corr_op",
            "fake_quant_op", "default_interpret", "default_impl"]
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode: Python-evaluated kernel body off-TPU."""
-    return jax.default_backend() != "tpu"
-
-
-def default_impl() -> str:
-    """Backend dispatch: the compiled kernel on TPU, the jnp oracle elsewhere."""
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def _resolve_impl(impl: str | None) -> str:
@@ -63,7 +53,6 @@ def kmeans_coreset_op(points: jnp.ndarray, k: int, iters: int = 4,
     if _resolve_impl(impl) == "ref":
         return ref.kmeans_coreset_ref(points.astype(jnp.float32), k=k,
                                       iters=iters)
-    interpret = default_interpret() if interpret is None else interpret
     padded, b = _pad_axis(points, 0, block_b)
     centers, radii, counts = kmeans_coreset_pallas(
         padded, k=k, iters=iters, block_b=block_b, interpret=interpret)
@@ -78,7 +67,6 @@ def importance_select_op(windows: jnp.ndarray, m: int, spread: float = 0.25,
     if _resolve_impl(impl) == "ref":
         return ref.importance_select_ref(windows.astype(jnp.float32), m=m,
                                          spread=spread)
-    interpret = default_interpret() if interpret is None else interpret
     padded, b = _pad_axis(windows, 0, block_b)
     idx, vals, weights = importance_select_pallas(
         padded, m=m, spread=spread, avg_width=avg_width, block_b=block_b,
@@ -87,33 +75,27 @@ def importance_select_op(windows: jnp.ndarray, m: int, spread: float = 0.25,
 
 
 def signature_corr_op(windows: jnp.ndarray, signatures: jnp.ndarray,
-                      block_b: int = 8, block_l: int = 8,
-                      interpret: bool | None = None,
+                      block_b: int = 8, interpret: bool | None = None,
                       impl: str | None = None) -> jnp.ndarray:
     """(B, T, C) vs (L, T, C) -> (B, L) correlations.
 
     This is the fleet simulator's memoization hot path: every node correlates
     its fresh window against the whole signature bank each slot, so the
-    batched form (B = all fleet nodes) is the one that must scale.  Under the
-    sharded fleet engine this op runs *inside* the shard_map manual region, so
-    B is the local node tile (N/d) — the block sizes clamp to the actual tile
-    so a small shard is one kernel block instead of being padded up 8x.
+    batched form (B = all fleet nodes) is the one that must scale.  The bank
+    (L = a handful of classes) is one kernel block; B tiles in blocks of
+    ``block_b`` (a multiple of 8).  Under the sharded fleet engine this op
+    runs *inside* the shard_map manual region, so B is the local node tile
+    (N/d): a tile smaller than ``block_b`` is one whole-tile block instead of
+    being padded up.
     """
     if _resolve_impl(impl) == "ref":
         return ref.signature_corr_ref(windows.astype(jnp.float32),
                                       signatures.astype(jnp.float32))
-    interpret = default_interpret() if interpret is None else interpret
-    block_b = max(1, min(block_b, windows.shape[0]))
-    block_l = max(1, min(block_l, signatures.shape[0]))
+    block_b = min(block_b, windows.shape[0])
     wp, b = _pad_axis(windows, 0, block_b)
-    # Signatures pad with zeros NOT edge: a zero signature correlates ~0 and
-    # never wins the memo argmax.
-    l = signatures.shape[0]
-    pad_l = (-l) % block_l
-    sp = jnp.pad(signatures, ((0, pad_l), (0, 0), (0, 0)))
-    out = signature_corr_pallas(wp, sp, block_b=block_b, block_l=block_l,
+    out = signature_corr_pallas(wp, signatures, block_b=block_b,
                                 interpret=interpret)
-    return out[:b, :l]
+    return out[:b]
 
 
 def fake_quant_op(x: jnp.ndarray, bits: int, per_channel: bool = False,
@@ -127,7 +109,6 @@ def fake_quant_op(x: jnp.ndarray, bits: int, per_channel: bool = False,
         out = ref.fake_quant_ref(x2d.astype(jnp.float32), bits=bits,
                                  per_channel=per_channel)
         return out.reshape(orig_shape).astype(orig_dtype)
-    interpret = default_interpret() if interpret is None else interpret
     r, c = x2d.shape
     block_r = min(256, r)
     block_c = min(512, c)

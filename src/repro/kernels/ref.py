@@ -4,6 +4,8 @@ These are THE definition of correctness: each kernel's test sweeps shapes and
 dtypes and asserts allclose against these functions.  They intentionally use
 only `jnp` ops (no pallas), in float32, with the exact same algorithmic
 choices the kernels make (fixed iteration budgets, strided init, etc.).
+Contractions run at ``Precision.HIGHEST`` so the oracles stay float32 on a
+TPU too, whose default f32 matmul rounds its inputs to bfloat16.
 
 Kernel inventory (the paper's fixed-function sensor hardware, §4.2, adapted
 to VMEM/MXU tiling):
@@ -17,6 +19,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 __all__ = ["kmeans_coreset_ref", "importance_select_ref", "signature_corr_ref",
            "fake_quant_ref"]
@@ -41,7 +45,7 @@ def kmeans_coreset_ref(points: jnp.ndarray, k: int, iters: int = 4):
         assign = jnp.argmin(d2, axis=-1)                    # (B, N)
         onehot = jax.nn.one_hot(assign, k, dtype=points.dtype)  # (B, N, k)
         counts = jnp.sum(onehot, axis=1)                    # (B, k)
-        sums = jnp.einsum("bnk,bnd->bkd", onehot, points)
+        sums = jnp.einsum("bnk,bnd->bkd", onehot, points, precision=_HIGHEST)
         new = jnp.where(counts[..., None] > 0,
                         sums / jnp.maximum(counts[..., None], 1.0), centers)
         return new, None
@@ -70,7 +74,8 @@ def _hw_importance(windows: jnp.ndarray, spread: float = 0.25,
     xp = jnp.pad(windows, ((0, 0), (pad, avg_width - 1 - pad), (0, 0)), mode="edge")
     # moving average along T for each (b, c)
     ma = jax.vmap(lambda w: jnp.stack(
-        [jnp.convolve(w[:, ci], kern, mode="valid") for ci in range(c)], axis=-1
+        [jnp.convolve(w[:, ci], kern, mode="valid", precision=_HIGHEST)
+         for ci in range(c)], axis=-1
     ))(xp)
     detr = jnp.abs(windows - ma)
     w = jnp.sum(detr, axis=-1)                               # (B, T)
@@ -100,7 +105,7 @@ def signature_corr_ref(windows: jnp.ndarray, signatures: jnp.ndarray) -> jnp.nda
     """
     wm = windows - jnp.mean(windows, axis=1, keepdims=True)
     sm = signatures - jnp.mean(signatures, axis=1, keepdims=True)
-    num = jnp.einsum("btc,ltc->blc", wm, sm)
+    num = jnp.einsum("btc,ltc->blc", wm, sm, precision=_HIGHEST)
     wn = jnp.sqrt(jnp.sum(wm * wm, axis=1))                 # (B, C)
     sn = jnp.sqrt(jnp.sum(sm * sm, axis=1))                 # (L, C)
     den = wn[:, None, :] * sn[None, :, :]

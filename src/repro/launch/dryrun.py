@@ -263,8 +263,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.time() - t0
 
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):      # jax 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         result["cost_analysis"] = {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

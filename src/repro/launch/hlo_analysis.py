@@ -113,16 +113,13 @@ def _split_computations(txt: str) -> dict[str, list[str]]:
     return comps
 
 
-# operand reference, optionally preceded by its inline type — newer jax
-# prints `dot(%lhs, %rhs)`, 0.4.x prints `dot(f32[64,64]{1,0} %lhs, ...)`
-_OPERAND = r"(?:([a-z0-9]+)\[([0-9,]*)\](?:\{[^}]*\})?\s+)?%?([\w.\-]+)"
+# operand reference: compiled HLO prints `dot(%lhs, %rhs)`, with no inline
+# operand types, so shapes come from the computation's symbol table
+_OPERAND = r"%?([\w.\-]+)"
 
 
-def _operand_shape(match_groups, symtab) -> list[int] | None:
-    """Shape of an _OPERAND match: inline type if printed, else symtab."""
-    dtype, dims, name = match_groups
-    if dtype is not None:
-        return [int(d) for d in dims.split(",") if d]
+def _operand_shape(name: str, symtab) -> list[int] | None:
+    """Shape of an operand named in an _OPERAND match, from ``symtab``."""
     entry = symtab.get(name)
     return None if entry is None else entry[1]
 
@@ -137,7 +134,7 @@ def _dot_flops(line: str, symtab: dict[str, tuple[str, list[int]]]) -> float:
     cd = re.search(r"lhs_contracting_dims=\{([0-9,]*)\}", line)
     contracted = 1
     if m and cd:
-        lhs_shape = _operand_shape(m.groups(), symtab)
+        lhs_shape = _operand_shape(m.group(1), symtab)
         if lhs_shape is not None:
             for d in cd.group(1).split(","):
                 if d and int(d) < len(lhs_shape):
@@ -156,7 +153,7 @@ def _conv_flops(line: str, symtab: dict[str, tuple[str, list[int]]]) -> float:
                   line)
     if not m:
         return 0.0
-    rhs_shape = _operand_shape(m.groups()[3:], symtab)
+    rhs_shape = _operand_shape(m.group(2), symtab)
     if rhs_shape is None:
         return -1.0
     # kernel: spatial... x in_ch x out_ch (exact dim order varies; product

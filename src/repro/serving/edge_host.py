@@ -583,16 +583,10 @@ def _check(cond: bool, msg: str) -> None:
         raise ValueError(msg)
 
 
-try:                                   # jax.core slimming across versions
-    _Tracer = jax.core.Tracer
-except AttributeError:                 # pragma: no cover - newest jax only
-    from jax._src.core import Tracer as _Tracer
-
-
 def _is_concrete(x) -> bool:
     """True when ``x`` carries actual values (not a jit/vmap tracer) — value
     validation only runs on the host ingest path, never during tracing."""
-    return not isinstance(x, _Tracer)
+    return not isinstance(x, jax.core.Tracer)
 
 
 def decode_wire_coresets(p: WirePayload):

@@ -31,38 +31,21 @@ __all__ = [
 
 
 def make_mesh_compat(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with all-Auto axis types across jax versions.
-
-    Newer jax wants ``axis_types=(AxisType.Auto, ...)`` spelled out for
-    meshes that mix manual ``shard_map`` regions with auto sharding; 0.4.x
-    has no ``AxisType`` and every mesh axis is implicitly auto.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``, as meshes that
+    mix manual ``shard_map`` regions with auto sharding need."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, axis_names=None):
-    """``shard_map`` across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., axis_names=, check_vma=)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=, check_rep=)``
-    where ``auto`` is the complement of the manual axes.  Library code calls
-    this wrapper with the manual ``axis_names`` (default: every mesh axis).
-    """
+    """``jax.shard_map`` over the manual ``axis_names`` (default: every mesh
+    axis), without the varying-manual-axes check."""
     manual = frozenset(mesh.axis_names) if axis_names is None \
         else frozenset(axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      auto=auto, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
+
 
 # Logical axis -> mesh axis (or tuple of mesh axes).  Mesh axes that do not
 # exist in the active mesh are dropped at resolution time, so one rule table

@@ -26,7 +26,7 @@ from repro.core import DEFER, fleet_alive_traces, fleet_harvest_traces, \
 from repro.core.recovery import init_generator
 from repro.data.sensors import class_signatures, har_stream
 from repro.models.har import har_init
-from repro.serving import (seeker_fleet_simulate,
+from repro.serving import (fleet_node_keys, seeker_fleet_simulate,
                            seeker_fleet_simulate_streamed)
 
 S, N = 12, 4
@@ -209,15 +209,20 @@ def test_swapped_label_tracks_regression(setup):
     NOT whatever comparing both nodes against track A would give (what the
     old shard body's ``preds == labels[:, None]`` did)."""
     key, wins, labels, harvest, kw = setup
-    wins_b, labels_b = har_stream(jax.random.fold_in(key, 3), S)
+    # stream B's seed is chosen so that the untrained host predicts some of
+    # B's labels: with zero correct slots both scorings would count 0
+    wins_b, labels_b = har_stream(jax.random.fold_in(key, 27), S)
     harvest2 = jnp.broadcast_to(harvest[:1], (2, S))   # same energy, 2 nodes
 
     streams = jnp.stack([wins, wins_b])                # node0=A, node1=B
     tracks = jnp.stack([labels, labels_b], axis=1)     # (S, 2)
     res = seeker_fleet_simulate(streams, harvest2, labels=tracks, **kw)
 
+    # a node's PRNG stream is fold_in(key, position): swapping the nodes
+    # swaps their keys too, so each stream keeps its own recovery draws
     swapped = seeker_fleet_simulate(
-        streams[::-1], harvest2, labels=tracks[:, ::-1], **kw)
+        streams[::-1], harvest2, labels=tracks[:, ::-1],
+        node_keys=fleet_node_keys(key, 2)[::-1], **kw)
     # per-node scoring is permutation-equivariant: same counts either way
     assert int(res["correct"]) == int(swapped["correct"])
     assert int(res["completed"]) == int(swapped["completed"])

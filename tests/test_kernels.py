@@ -11,7 +11,8 @@ from repro.kernels import ref
 
 
 @pytest.mark.parametrize("b", [1, 7, 8, 24])
-@pytest.mark.parametrize("n,d", [(60, 4), (32, 2), (64, 8)])
+# (60, 2): the paper's per-channel (time, value) clouds of a HAR window
+@pytest.mark.parametrize("n,d", [(60, 4), (32, 2), (64, 8), (60, 2)])
 @pytest.mark.parametrize("k", [4, 12, 16])
 def test_kmeans_kernel_matches_ref(b, n, d, k, key):
     pts = jax.random.normal(key, (b, n, d))
@@ -34,7 +35,8 @@ def test_kmeans_kernel_dtypes(dtype, key):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("b,t,c", [(4, 60, 3), (8, 48, 1), (13, 64, 5)])
+@pytest.mark.parametrize("b,t,c", [(4, 60, 3), (8, 48, 1), (13, 64, 5),
+                                   (8, 60, 3)])
 @pytest.mark.parametrize("m", [8, 20])
 def test_importance_kernel_matches_ref(b, t, c, m, key):
     w = jax.random.normal(key, (b, t, c))
@@ -47,7 +49,9 @@ def test_importance_kernel_matches_ref(b, t, c, m, key):
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("b,l", [(4, 5), (16, 12), (9, 3)])
+# L=12 is the HAR bank: B=3 is the 3-sensor system (one whole-tile block),
+# B=13 pads to two blocks of 8
+@pytest.mark.parametrize("b,l", [(4, 5), (16, 12), (9, 3), (3, 12), (13, 12)])
 def test_corr_kernel_matches_ref(b, l, key):
     w = jax.random.normal(key, (b, 60, 3))
     s = jax.random.normal(jax.random.fold_in(key, 1), (l, 60, 3))

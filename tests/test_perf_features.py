@@ -104,8 +104,8 @@ def test_spec_for_prefix_fallback():
     s = shd.spec_for(("batch",), (4,), mesh, shd.PURE_DP_RULES)
     assert s == P(("pod", "data")), s
     s = shd.spec_for(("batch",), (2,), mesh, shd.PURE_DP_RULES)
-    assert s in (P("pod"), P(("pod",))), s   # singleton unwraps; newer jax
-    # normalizes the two spellings to equality, 0.4.x does not
+    assert s in (P("pod"), P(("pod",))), s   # singleton unwraps; jax
+    # treats the two spellings as equal
     s = shd.spec_for(("batch",), (3,), mesh, shd.PURE_DP_RULES)
     assert s == P(None), s
     print("OK")
