@@ -1,0 +1,9 @@
+"""The benchmark's CPU tests import ``bench`` and the program from the
+checkout's root."""
+import sys
+from pathlib import Path
+
+_ROOT = Path(__file__).resolve().parents[2]
+for p in (str(_ROOT), str(_ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
