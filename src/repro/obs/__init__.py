@@ -1,11 +1,12 @@
-"""Fleet-wide observability: metrics registry, span tracer, compile guard.
+"""Fleet-wide observability: metrics registry, spans, scopes, compile guard.
 
 One telemetry substrate for every engine and the host tier (ISSUE 8):
 
 * :mod:`repro.obs.registry` — declared-once counter/gauge/histogram lanes on
   a jit-friendly pytree; exact int accounting, psum-able, resume-exact;
-* :mod:`repro.obs.trace` — wall-clock spans with the ``block_until_ready``
-  flush idiom, exported as Chrome-trace/Perfetto JSON;
+* :mod:`repro.obs.trace` — host spans and registered layer scopes on the
+  profiler's clock, plus an opt-in wall-clock buffer (``block_until_ready``
+  flush idiom, Chrome-trace/Perfetto JSON export);
 * :mod:`repro.obs.compile_guard` — (re)trace events as a tracked,
   budget-guarded metric (the generalized ``serve_trace_count`` probe).
 """

@@ -1,24 +1,27 @@
-"""Wall-clock span tracer with Chrome-trace/Perfetto JSON export.
+"""Program spans on the profiler's clock, and the registry of scope names
+that label the fleet scan's layers in the device trace.
 
-The registry (:mod:`repro.obs.registry`) measures *what the simulation did*;
-this module measures *where the wall-clock went* — edge-scan segments, host
-microbatches, jit (re)traces, benchmark phases.  Spans are recorded with
-:func:`span`, a context manager that can **flush async dispatch** before
-stamping the end time: jax returns futures, so a naive ``perf_counter``
-around a jitted call times the dispatch, not the work.  Pass the result
-arrays (or a callable producing them) as ``flush=`` and the span blocks via
-``jax.block_until_ready`` before closing — the honest-timing idiom the
-benchmarks already use, made structural.
+Spans (:func:`span`) mark host-side phases: fleet argument preparation,
+the scan's dispatch, the eager aggregates, streamed segments, benchmark
+phases.  Each span always enters ``jax.profiler.TraceAnnotation``, so
+under a profiler trace it lands on the host plane on the same clock as the
+device's ops, and costs one annotation otherwise.
 
-Tracing is **off by default** and the disabled path does nothing at all (no
-clock reads, no flush), so instrumented library code — the streamed fleet
-driver, the host serve loop — is perturbation-free unless a tool opts in
-with :func:`enable`.
+Scopes (:func:`scope`) name the layers of traced code: each enters
+``jax.named_scope`` with a name from :data:`SCOPES`, which XLA keeps in
+every op's metadata (the ``tf_op`` stat of a device op in the trace).
+``bench/xplane.py`` reduces a trace to device seconds per scope.  Names
+are dotted, never ``/``: the name stack uses ``/`` between levels, and a
+scope traced under ``vmap`` reads ``vmap(seeker.x)`` there.
 
-Export (:func:`export_chrome_trace`) writes the Chrome trace-event JSON
-format (``{"traceEvents": [...]}``, ``ph: "X"`` complete events in µs),
-loadable directly in Perfetto / ``chrome://tracing``; CI uploads the file
-per PR next to the BENCH artifacts.
+The wall-clock buffer is opt-in: after :func:`enable`, spans also record
+``perf_counter`` intervals, optionally flushing async dispatch first
+(``flush=``: jax returns futures, so a span around a jitted call would
+otherwise time the dispatch, not the work), and
+:func:`export_chrome_trace` writes them as Chrome-trace/Perfetto JSON
+(``{"traceEvents": [...]}``, ``ph: "X"`` complete events in µs); CI
+uploads the file per PR next to the BENCH artifacts.  Disabled, a span
+reads no clock and flushes nothing.
 """
 from __future__ import annotations
 
@@ -30,8 +33,29 @@ import time
 
 import jax
 
-__all__ = ["enable", "enabled", "clear", "span", "instant", "events",
-           "export_chrome_trace"]
+__all__ = ["SCOPES", "scope", "enable", "enabled", "clear", "span",
+           "instant", "events", "export_chrome_trace"]
+
+SCOPES = {
+    "seeker.corr": "the batched signature-bank correlation of the fleet "
+                   "(signature_corr_op)",
+    "seeker.decide": "predictor, decision ladder, payload bookkeeping and "
+                     "supercap update of each node",
+    "seeker.edge_dnn": "the on-node quantized DNN (har_apply_quantized, the "
+                       "fake_quant kernel)",
+    "seeker.aac_coreset": "AAC's k and the per-channel clustering coreset",
+    "seeker.importance_coreset": "the importance-sampling coreset",
+    "seeker.recover_cluster": "host recovery of a window from its cluster "
+                              "coreset",
+    "seeker.recover_sampling": "host GAN recovery of a window from its "
+                               "sampled coreset",
+    "seeker.host_dnn": "the host's full-precision DNN on both recovered "
+                       "windows and the logit select",
+    "seeker.lanes": "the scan step's lanes: churn freeze, brown-out "
+                    "hysteresis, output masks, telemetry",
+    "seeker.collectives": "the sharded engine's psums of the fleet "
+                          "aggregates and telemetry",
+}
 
 _LOCK = threading.Lock()
 _ENABLED = False
@@ -39,8 +63,17 @@ _EVENTS: list[dict] = []
 _T0_NS = time.perf_counter_ns()
 
 
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name registered in :data:`SCOPES`;
+    called while tracing, so an unregistered name fails at trace time."""
+    if name not in SCOPES:
+        raise ValueError(f"scope {name!r} is not registered in "
+                         f"repro.obs.trace.SCOPES")
+    return jax.named_scope(name)
+
+
 def enable(on: bool = True) -> None:
-    """Globally switch span recording on/off (off = zero-overhead no-ops)."""
+    """Globally switch the wall-clock span buffer on/off."""
     global _ENABLED
     _ENABLED = on
 
@@ -67,27 +100,29 @@ def _record(ev: dict) -> None:
 @contextlib.contextmanager
 def span(name: str, cat: str = "repro", args: dict | None = None,
          flush=None):
-    """Record a wall-clock span around a block.
+    """A host span around a block: a profiler ``TraceAnnotation`` always,
+    and a wall-clock event in the buffer when tracing is enabled.
 
     ``flush``: jax arrays (any pytree) or a zero-arg callable returning
-    them — ``jax.block_until_ready`` runs on them before the end timestamp,
-    so asynchronously-dispatched device work is *inside* the span instead of
-    leaking into whatever is timed next.  When tracing is disabled the body
-    runs untouched: no clock, no flush, no event.
+    them — with the buffer enabled, ``jax.block_until_ready`` runs on them
+    before the end timestamp, so asynchronously-dispatched device work is
+    *inside* the span instead of leaking into whatever is timed next.
+    Disabled, the body runs untouched: no clock, no flush, no event.
     """
-    if not _ENABLED:
-        yield
-        return
-    t0 = _now_us()
-    try:
-        yield
-    finally:
-        if flush is not None:
-            jax.block_until_ready(flush() if callable(flush) else flush)
-        _record({"name": name, "cat": cat, "ph": "X", "ts": t0,
-                 "dur": _now_us() - t0, "pid": os.getpid(),
-                 "tid": threading.get_ident(),
-                 **({"args": args} if args else {})})
+    with jax.profiler.TraceAnnotation(name):
+        if not _ENABLED:
+            yield
+            return
+        t0 = _now_us()
+        try:
+            yield
+        finally:
+            if flush is not None:
+                jax.block_until_ready(flush() if callable(flush) else flush)
+            _record({"name": name, "cat": cat, "ph": "X", "ts": t0,
+                     "dur": _now_us() - t0, "pid": os.getpid(),
+                     "tid": threading.get_ident(),
+                     **({"args": args} if args else {})})
 
 
 def instant(name: str, cat: str = "repro",

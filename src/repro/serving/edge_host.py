@@ -47,6 +47,7 @@ from ..core.coreset import importance_coreset
 from ..models.har import (HARConfig, har_act_buffer, har_apply,
                           har_apply_aux, har_apply_quantized,
                           har_apply_stage)
+from ..obs.trace import scope
 
 __all__ = ["SeekerNodeState", "seeker_node_init", "seeker_sensor_step",
            "seeker_sensor_step_given_corr", "seeker_host_step",
@@ -131,70 +132,77 @@ def seeker_sensor_step_given_corr(
     (see :class:`repro.serving.fleet_lanes.TaskLaneConfig`); ``None`` keeps
     the homogeneous-fleet jaxpr bitwise.
     """
-    max_corr = jnp.max(corr)
-    memo_label = jnp.argmax(corr).astype(jnp.int32)
+    with scope("seeker.decide"):
+        max_corr = jnp.max(corr)
+        memo_label = jnp.argmax(corr).astype(jnp.int32)
 
-    predictor = predictor_update(state.predictor, harvested_uj)
-    forecast = predictor_forecast(predictor)
-    outcome = choose_decision(
-        max_corr, state.stored_uj, forecast, costs,
-        corr_threshold=corr_threshold,
-        harvested_uj=harvested_uj if strict_energy else None,
-        cost_scale=cost_scale)
-    decision = outcome.decision
+        predictor = predictor_update(state.predictor, harvested_uj)
+        forecast = predictor_forecast(predictor)
+        outcome = choose_decision(
+            max_corr, state.stored_uj, forecast, costs,
+            corr_threshold=corr_threshold,
+            harvested_uj=harvested_uj if strict_energy else None,
+            cost_scale=cost_scale)
+        decision = outcome.decision
 
     # --- D2: quantized DNN on-node (executed unconditionally, masked out) ---
-    logits = har_apply_quantized(qdnn_params, window[None], quant_bits)[0]
-    dnn_label = jnp.argmax(logits).astype(jnp.int32)
+    with scope("seeker.edge_dnn"):
+        logits = har_apply_quantized(qdnn_params, window[None],
+                                     quant_bits)[0]
+        dnn_label = jnp.argmax(logits).astype(jnp.int32)
 
     # --- D3: AAC clustering coreset (per-channel, as the paper's FIFO) -----
-    if aac_table is not None:
-        k_sel = select_k(aac_table, state.prev_label,
-                         state.stored_uj + forecast)
-    else:
-        k_sel = jnp.asarray(k_max, jnp.int32)
-    cs = channel_cluster_coresets(window, k=k_max, iters=4)  # (C, k, 2)
-    # zero out clusters beyond the AAC-selected k (static k_max buffer)
-    keep = jnp.arange(k_max) < k_sel
-    centers = jnp.where(keep[None, :, None], cs.centers, 0.0)
-    radii = jnp.where(keep[None, :], cs.radii, 0.0)
-    counts = jnp.where(keep[None, :], cs.counts, 0)
+    with scope("seeker.aac_coreset"):
+        if aac_table is not None:
+            k_sel = select_k(aac_table, state.prev_label,
+                             state.stored_uj + forecast)
+        else:
+            k_sel = jnp.asarray(k_max, jnp.int32)
+        cs = channel_cluster_coresets(window, k=k_max, iters=4)  # (C, k, 2)
+        # zero out clusters beyond the AAC-selected k (static k_max buffer)
+        keep = jnp.arange(k_max) < k_sel
+        centers = jnp.where(keep[None, :, None], cs.centers, 0.0)
+        radii = jnp.where(keep[None, :], cs.radii, 0.0)
+        counts = jnp.where(keep[None, :], cs.counts, 0)
 
     # --- D4: importance-sampling coreset -----------------------------------
-    sc = importance_coreset(window, m_samples, key)
+    with scope("seeker.importance_coreset"):
+        sc = importance_coreset(window, m_samples, key)
 
     # --- bookkeeping --------------------------------------------------------
-    t = window.shape[0]
-    c = window.shape[1] if window.ndim > 1 else 1
-    bytes_by_decision = jnp.asarray([
-        2.0,                                              # D0: a label
-        2.0, 2.0,                                         # D1/D2: a result
-        0.0,                                              # D3: AAC (below)
-        float(sampling_payload_bytes(m_samples, channels=c)),
-        0.0,                                              # DEFER
-    ])
-    aac_bytes = (k_sel.astype(jnp.float32) * 3.0
-                 + jnp.ceil(k_sel.astype(jnp.float32) / 2.0)) * c
-    payload = jnp.where(decision == D3_CLUSTER, aac_bytes,
-                        bytes_by_decision[decision])
+    with scope("seeker.decide"):
+        t = window.shape[0]
+        c = window.shape[1] if window.ndim > 1 else 1
+        bytes_by_decision = jnp.asarray([
+            2.0,                                          # D0: a label
+            2.0, 2.0,                                     # D1/D2: a result
+            0.0,                                          # D3: AAC (below)
+            float(sampling_payload_bytes(m_samples, channels=c)),
+            0.0,                                          # DEFER
+        ])
+        aac_bytes = (k_sel.astype(jnp.float32) * 3.0
+                     + jnp.ceil(k_sel.astype(jnp.float32) / 2.0)) * c
+        payload = jnp.where(decision == D3_CLUSTER, aac_bytes,
+                            bytes_by_decision[decision])
 
-    if strict_energy:
-        stored = supercap_step_direct(state.stored_uj, harvested_uj,
-                                      outcome.spend)
-    else:
-        stored = supercap_step(state.stored_uj, harvested_uj, outcome.spend)
-    label = jnp.where(decision == D0_MEMO, memo_label,
-                      jnp.where(decision == D2_DNN_QUANT, dnn_label, -1))
-    prev = jnp.where(label >= 0, label, state.prev_label)
-    new_state = SeekerNodeState(stored_uj=stored, predictor=predictor,
-                                prev_label=prev)
-    return SensorStepOut(
-        decision=decision, label_or_neg=label.astype(jnp.int32),
-        logits=jnp.where(decision == D2_DNN_QUANT, logits, 0.0),
-        coreset_centers=centers, coreset_radii=radii, coreset_counts=counts,
-        coreset_k=k_sel, samp_idx=sc.indices, samp_vals=sc.values,
-        samp_mean=sc.mean, samp_var=sc.var,
-        payload_bytes=payload, state=new_state)
+        if strict_energy:
+            stored = supercap_step_direct(state.stored_uj, harvested_uj,
+                                          outcome.spend)
+        else:
+            stored = supercap_step(state.stored_uj, harvested_uj,
+                                   outcome.spend)
+        label = jnp.where(decision == D0_MEMO, memo_label,
+                          jnp.where(decision == D2_DNN_QUANT, dnn_label, -1))
+        prev = jnp.where(label >= 0, label, state.prev_label)
+        new_state = SeekerNodeState(stored_uj=stored, predictor=predictor,
+                                    prev_label=prev)
+        return SensorStepOut(
+            decision=decision, label_or_neg=label.astype(jnp.int32),
+            logits=jnp.where(decision == D2_DNN_QUANT, logits, 0.0),
+            coreset_centers=centers, coreset_radii=radii,
+            coreset_counts=counts, coreset_k=k_sel, samp_idx=sc.indices,
+            samp_vals=sc.values, samp_mean=sc.mean, samp_var=sc.var,
+            payload_bytes=payload, state=new_state)
 
 
 def seeker_host_step(out: SensorStepOut, *, host_params: dict,
@@ -207,21 +215,25 @@ def seeker_host_step(out: SensorStepOut, *, host_params: dict,
     k1, k2 = jax.random.split(key)
     cs = ClusterCoreset(out.coreset_centers, out.coreset_radii,
                         out.coreset_counts)
-    win_cluster = recover_cluster_window(cs, k1, t)
+    with scope("seeker.recover_cluster"):
+        win_cluster = recover_cluster_window(cs, k1, t)
     sc = SamplingCoreset(out.samp_idx, out.samp_vals,
                          jnp.ones_like(out.samp_idx, jnp.float32),
                          out.samp_mean, out.samp_var)
-    win_sampling = recover_sampling_window(gen_params, sc, k2, t)
+    with scope("seeker.recover_sampling"):
+        win_sampling = recover_sampling_window(gen_params, sc, k2, t)
 
-    logit_cluster = har_apply(host_params, win_cluster[None])[0]
-    logit_sampling = har_apply(host_params, win_sampling[None])[0]
-    onehot = (jax.nn.one_hot(out.label_or_neg, logit_cluster.shape[-1])
-              * 8.0)                                     # confident on-node result
-    return jnp.where(out.decision == D3_CLUSTER, logit_cluster,
-                     jnp.where(out.decision == D4_SAMPLING, logit_sampling,
-                               jnp.where(out.decision == DEFER,
-                                         jnp.zeros_like(logit_cluster),
-                                         onehot)))
+    with scope("seeker.host_dnn"):
+        logit_cluster = har_apply(host_params, win_cluster[None])[0]
+        logit_sampling = har_apply(host_params, win_sampling[None])[0]
+        onehot = (jax.nn.one_hot(out.label_or_neg, logit_cluster.shape[-1])
+                  * 8.0)                             # confident on-node result
+        return jnp.where(out.decision == D3_CLUSTER, logit_cluster,
+                         jnp.where(out.decision == D4_SAMPLING,
+                                   logit_sampling,
+                                   jnp.where(out.decision == DEFER,
+                                             jnp.zeros_like(logit_cluster),
+                                             onehot)))
 
 
 # ---------------------------------------------------------------------------

@@ -142,6 +142,7 @@ from ..obs import (MetricsSpec, categorical_counts, compile_event,
                    int_pair_sum, int_pair_total, metrics_init,
                    metrics_merge, metrics_psum, spec_union)
 from ..obs import trace as obs_trace
+from ..obs.trace import scope
 from ..sharding import make_mesh_compat, node_mesh_axes, shard_map_compat
 from .edge_host import (IntermittentState, SeekerNodeState,
                         intermittent_fleet_init, intermittent_lane_step,
@@ -303,7 +304,8 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
         # memoization hot path: one batched signature-bank correlation for
         # the whole (local) fleet — under shard_map this is the (N/d, L)
         # tile, so the Pallas/ref kernel runs per-shard with no collectives
-        corr = signature_corr_op(win_t, signatures)       # (B, L)
+        with scope("seeker.corr"):
+            corr = signature_corr_op(win_t, signatures)   # (B, L)
 
         if task is None:
             out = jax.vmap(
@@ -410,7 +412,9 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
         # the per-slot alive lane: the exogenous trace composed with the
         # endogenous brown-out flag carried through the scan — a node runs
         # only when its trace says so AND its supercap hysteresis allows
-        alive_eff = (alive_t & ~browned) if brownout is not None else alive_t
+        with scope("seeker.lanes"):
+            alive_eff = ((alive_t & ~browned) if brownout is not None
+                         else alive_t)
         if shared_stream:
             win_t = jnp.broadcast_to(win_t[None], (n,) + win_t.shape)
 
@@ -445,62 +449,66 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
                                          aux_params),
                     (st_g, ks_g, it_g, tk_g, w_g, h_g)))
 
-        # --- churn lane: a dead node harvests nothing, freezes its whole
-        # carry (charge, predictor, AAC continuity AND its PRNG stream — on
-        # rejoin it continues exactly where it browned out), and emits DEFER
-        # with zero payload.  With an all-True trace every select picks the
-        # freshly-computed value, so the churn-free run is bitwise unchanged.
-        def keep(new, old):
-            a = alive_eff.reshape((n,) + (1,) * (new.ndim - 1))
-            return jnp.where(a, new, old)
+        with scope("seeker.lanes"):
+            # --- churn lane: a dead node harvests nothing, freezes its
+            # whole carry (charge, predictor, AAC continuity AND its PRNG
+            # stream — on rejoin it continues exactly where it browned
+            # out), and emits DEFER with zero payload.  With an all-True
+            # trace every select picks the freshly-computed value, so the
+            # churn-free run is bitwise unchanged.
+            def keep(new, old):
+                a = alive_eff.reshape((n,) + (1,) * (new.ndim - 1))
+                return jnp.where(a, new, old)
 
-        new_state = jax.tree_util.tree_map(keep, new_state, state)
-        new_keys = keep(new_keys, keys)
-        if intermittent is not None:
-            # suspended staged activations freeze through dead AND browned-
-            # out slots like every other carry lane — suspend-across-
-            # brown-out falls out of the same select
-            new_it = jax.tree_util.tree_map(keep, new_it, it)
-        if brownout is not None:
-            # --- endogenous brown-out: the MCU is down but the harvester
-            # keeps trickle-charging the supercap, so a browned-out (yet
-            # exogenously-present) node's charge still integrates income;
-            # an exogenously-dead node stays fully frozen (PR-4 lane).
-            trickle = supercap_step(state.stored_uj, harv_t, 0.0)
-            stored = jnp.where(alive_eff, new_state.stored_uj,
-                               jnp.where(alive_t, trickle, state.stored_uj))
-            new_state = new_state._replace(stored_uj=stored)
-            # hysteresis on the POST-slot charge: running nodes brown out
-            # below off_uj, browned-out nodes rejoin at restart_uj; the flag
-            # freezes (like everything else) through exogenously-dead slots
-            next_browned = jnp.where(browned, stored < brownout.restart_uj,
-                                     stored < brownout.off_uj)
-            next_browned = jnp.where(alive_t, next_browned, browned)
-        else:
-            next_browned = browned
-        out_trace = {
-            "decision": jnp.where(alive_eff, trace["decision"], DEFER),
-            "payload": jnp.where(alive_eff, trace["payload"], 0.0),
-            "stored": new_state.stored_uj,
-            "k": jnp.where(alive_eff, trace["k"], 0),
-            "logits": jnp.where(alive_eff[:, None], trace["logits"], 0.0),
-            "alive": alive_eff,          # exogenous ∧ ¬browned_out
-            "brownout": browned,         # the flag the slot was entered with
-            "bo_event": next_browned & ~browned,   # brown-out onsets
-        }
-        if intermittent is not None:
-            # a dead/browned-out node ran no lane this slot: its emission
-            # lane is masked like the decision lane (the label/conf/src
-            # fields are only meaningful where it_emit > 0)
-            out_trace.update({
-                "it_emit": jnp.where(alive_eff, trace["it_emit"], 0),
-                "it_label": trace["it_label"],
-                "it_conf": trace["it_conf"],
-                "it_src": trace["it_src"],
-                "it_stage": trace["it_stage"],
-            })
-        new_metrics = (None if telemetry is None else _update_fleet_lanes(
-            telemetry, metrics, out_trace, alive_t, active, tasks))
+            new_state = jax.tree_util.tree_map(keep, new_state, state)
+            new_keys = keep(new_keys, keys)
+            if intermittent is not None:
+                # suspended staged activations freeze through dead AND browned-
+                # out slots like every other carry lane — suspend-across-
+                # brown-out falls out of the same select
+                new_it = jax.tree_util.tree_map(keep, new_it, it)
+            if brownout is not None:
+                # --- endogenous brown-out: the MCU is down but the harvester
+                # keeps trickle-charging the supercap, so a browned-out (yet
+                # exogenously-present) node's charge still integrates income;
+                # an exogenously-dead node stays fully frozen (PR-4 lane).
+                trickle = supercap_step(state.stored_uj, harv_t, 0.0)
+                stored = jnp.where(alive_eff, new_state.stored_uj,
+                                   jnp.where(alive_t, trickle,
+                                             state.stored_uj))
+                new_state = new_state._replace(stored_uj=stored)
+                # hysteresis on the POST-slot charge: running nodes brown
+                # out below off_uj, browned-out nodes rejoin at restart_uj;
+                # the flag freezes (like everything else) through
+                # exogenously-dead slots
+                next_browned = jnp.where(browned, stored < brownout.restart_uj,
+                                         stored < brownout.off_uj)
+                next_browned = jnp.where(alive_t, next_browned, browned)
+            else:
+                next_browned = browned
+            out_trace = {
+                "decision": jnp.where(alive_eff, trace["decision"], DEFER),
+                "payload": jnp.where(alive_eff, trace["payload"], 0.0),
+                "stored": new_state.stored_uj,
+                "k": jnp.where(alive_eff, trace["k"], 0),
+                "logits": jnp.where(alive_eff[:, None], trace["logits"], 0.0),
+                "alive": alive_eff,          # exogenous ∧ ¬browned_out
+                "brownout": browned,         # the flag entering the slot
+                "bo_event": next_browned & ~browned,   # brown-out onsets
+            }
+            if intermittent is not None:
+                # a dead/browned-out node ran no lane this slot: its emission
+                # lane is masked like the decision lane (the label/conf/src
+                # fields are only meaningful where it_emit > 0)
+                out_trace.update({
+                    "it_emit": jnp.where(alive_eff, trace["it_emit"], 0),
+                    "it_label": trace["it_label"],
+                    "it_conf": trace["it_conf"],
+                    "it_src": trace["it_src"],
+                    "it_stage": trace["it_stage"],
+                })
+            new_metrics = (None if telemetry is None else _update_fleet_lanes(
+                telemetry, metrics, out_trace, alive_t, active, tasks))
         return FleetCarry(node=new_state, keys=new_keys,
                           brownout=next_browned, intermittent=new_it,
                           telemetry=new_metrics), out_trace
@@ -613,16 +621,21 @@ def _build_fleet_run_sharded(mesh, axis_names: tuple[str, ...],
                               host_params, gen_params, aac_table,
                               aux_params),
             carry0, (xs_w, xs_h, xs_alive, xs_slots))
+
+        def psum(x):
+            with scope("seeker.collectives"):
+                return jax.lax.psum(x, axis_names)
+
         aggs = _fleet_aggregates(
             traces, xs_alive, labels, per_node_labels, intermittent,
             xs_slots[0] if intermittent is not None else 0,
-            tasks=tasks, task=task, mask=mask,
-            reduce=lambda x: jax.lax.psum(x, axis_names))
+            tasks=tasks, task=task, mask=mask, reduce=psum)
         # registry lanes are summed per shard then psum'd component-wise;
         # the psum'd delta is replicated (out-spec P() per lane)
-        final = final._replace(
-            telemetry=None if telemetry is None else metrics_psum(
-                telemetry, final.telemetry, axis_names))
+        if telemetry is not None:
+            with scope("seeker.collectives"):
+                final = final._replace(telemetry=metrics_psum(
+                    telemetry, final.telemetry, axis_names))
         return traces, final, aggs
 
     in_specs = (nodes, nodes, nodes,   # state0 (pytree), keys0, browned0
@@ -1085,106 +1098,111 @@ def seeker_fleet_simulate(windows: jnp.ndarray, harvest: jnp.ndarray, *,
     resuming; ``correct`` then sums ladder + lane completions, each scored
     against its source slot's label.
     """
-    costs = costs or EnergyCosts()
-    key = key if key is not None else jax.random.PRNGKey(0)
-    n, s = harvest.shape
-    assert windows.ndim in (3, 4), f"windows must be (S,T,C) or (N,S,T,C), got {windows.shape}"
-    shared_stream = windows.ndim == 3
-    if shared_stream:
-        assert windows.shape[0] == s, (windows.shape, s)
-        xs_windows = windows                                  # (S, T, C)
-    else:
-        assert windows.shape[:2] == (n, s), (windows.shape, n, s)
-        xs_windows = jnp.moveaxis(windows, 0, 1)              # (S, N, T, C)
-    t = windows.shape[-2]
-    labels, per_node_labels = _resolve_labels(labels, s, n, shared_stream)
-    alive_t = _resolve_alive(alive, n, s).T                   # (S, N)
+    with obs_trace.span("fleet.prep", cat="fleet"):
+        costs = costs or EnergyCosts()
+        key = key if key is not None else jax.random.PRNGKey(0)
+        n, s = harvest.shape
+        assert windows.ndim in (3, 4), \
+            f"windows must be (S,T,C) or (N,S,T,C), got {windows.shape}"
+        shared_stream = windows.ndim == 3
+        if shared_stream:
+            assert windows.shape[0] == s, (windows.shape, s)
+            xs_windows = windows                              # (S, T, C)
+        else:
+            assert windows.shape[:2] == (n, s), (windows.shape, n, s)
+            xs_windows = jnp.moveaxis(windows, 0, 1)          # (S, N, T, C)
+        t = windows.shape[-2]
+        labels, per_node_labels = _resolve_labels(labels, s, n, shared_stream)
+        alive_t = _resolve_alive(alive, n, s).T               # (S, N)
 
-    state0 = _stack_pad_state(state0, n, 0, predictor_window, initial_uj)
-    keys0 = node_keys if node_keys is not None else fleet_node_keys(key, n)
-    browned0 = _resolve_brownout0(brownout_state0, state0, brownout, n)
-    _validate_intermittent_args(intermittent, intermittent_state0,
-                                aux_params, n)
-    tasks, task = _resolve_tasks(tasks, task, n)
-    host_params = _resolve_task_host(task, host_params)
-    tel_spec = _resolve_telemetry(telemetry, intermittent, task)
-    run_fn = _build_fleet_run(har_cfg, costs, quant_bits, k_max, m_samples,
-                              corr_threshold, shared_stream, node_block,
-                              brownout, donate, intermittent, tel_spec,
-                              task)
-    it0 = None
-    if intermittent is not None:
-        it0 = (intermittent_state0 if intermittent_state0 is not None
-               else intermittent_fleet_init(n, har_cfg))
-    xs_slots = jnp.arange(slot0, slot0 + s, dtype=jnp.int32)
-    traces, final = run_fn(
-        state0, keys0, browned0, it0, tasks, xs_windows, harvest.T,
-        alive_t, xs_slots, signatures, qdnn_params, host_params, gen_params,
-        aac_table, aux_params)
-    final_state, final_keys = final.node, final.keys
-    final_brownout, final_intermittent = final.brownout, final.intermittent
-    tel_delta = final.telemetry
-
-    aggs = _fleet_aggregates(traces, alive_t, labels, per_node_labels,
-                             intermittent, slot0, tasks=tasks, task=task)
-    out = {
-        "decisions": traces["decision"],                      # (S, N)
-        "payload_bytes": traces["payload"],                   # (S, N)
-        "stored_uj": traces["stored"],                        # (S, N)
-        "k_trace": traces["k"],                               # (S, N)
-        "logits": traces["logits"],                           # (S, N, L)
-        "preds": jnp.argmax(traces["logits"], axis=-1),       # (S, N)
-        "alive": traces["alive"],                             # (S, N)
-        "brownout": traces["brownout"],                       # (S, N)
-        "bytes_on_wire": aggs["bytes_on_wire"],
-        "bytes_on_wire_i32": aggs["bytes_on_wire_i32"],
-        "decision_histogram": aggs["decision_histogram"],
-        "completed": aggs["completed"],
-        "alive_slots": aggs["alive_slots"],
-        "brownout_slots": aggs["brownout_slots"],
-        "brownout_events": aggs["brownout_events"],
-        "completed_frac": aggs["completed"]
-            / jnp.maximum(aggs["alive_slots"], 1),
-        "raw_bytes_per_window": jnp.asarray(
-            float(raw_payload_bytes(t)) * windows.shape[-1], jnp.float32),
-        "final_state": final_state,
-        "final_keys": final_keys,
-        "final_brownout": final_brownout,
-    }
-    if tel_spec is not None:
-        out["telemetry"] = metrics_merge(tel_spec, telemetry_state0,
-                                         tel_delta)
-        out["telemetry_spec"] = tel_spec
-    if intermittent is not None:
-        out.update({
-            "it_emit": traces["it_emit"],                     # (S, N)
-            "it_label": traces["it_label"],                   # (S, N)
-            "it_conf": traces["it_conf"],                     # (S, N)
-            "it_src": traces["it_src"],                       # (S, N)
-            "it_stage": traces["it_stage"],                   # (S, N)
-            "it_full": aggs["it_full"],
-            "it_early": aggs["it_early"],
-            "final_intermittent": final_intermittent,
-        })
-    if labels is not None:
-        out["correct"] = aggs["correct"]
-        out["fleet_accuracy"] = (aggs["correct"]
-                                 / jnp.maximum(aggs["completed"], 1))
+        state0 = _stack_pad_state(state0, n, 0, predictor_window, initial_uj)
+        keys0 = node_keys if node_keys is not None else fleet_node_keys(key, n)
+        browned0 = _resolve_brownout0(brownout_state0, state0, brownout, n)
+        _validate_intermittent_args(intermittent, intermittent_state0,
+                                    aux_params, n)
+        tasks, task = _resolve_tasks(tasks, task, n)
+        host_params = _resolve_task_host(task, host_params)
+        tel_spec = _resolve_telemetry(telemetry, intermittent, task)
+        run_fn = _build_fleet_run(har_cfg, costs, quant_bits, k_max, m_samples,
+                                  corr_threshold, shared_stream, node_block,
+                                  brownout, donate, intermittent, tel_spec,
+                                  task)
+        it0 = None
         if intermittent is not None:
-            out["correct_ladder"] = aggs["correct_ladder"]
-            out["it_correct_full"] = aggs["it_correct_full"]
-            out["it_correct_early"] = aggs["it_correct_early"]
-    if task is not None:
-        out["task_names"] = task.names
-        out["tasks"] = tasks
-        out["completed_by_task"] = aggs["completed_by_task"]
-        out["deadline_miss_by_task"] = aggs["deadline_miss_by_task"]
+            it0 = (intermittent_state0 if intermittent_state0 is not None
+                   else intermittent_fleet_init(n, har_cfg))
+        xs_slots = jnp.arange(slot0, slot0 + s, dtype=jnp.int32)
+        harvest_t = harvest.T                                 # (S, N)
+    with obs_trace.span("fleet.run", cat="fleet"):
+        traces, final = run_fn(
+            state0, keys0, browned0, it0, tasks, xs_windows, harvest_t,
+            alive_t, xs_slots, signatures, qdnn_params, host_params,
+            gen_params, aac_table, aux_params)
+    with obs_trace.span("fleet.aggregates", cat="fleet"):
+        final_state, final_keys = final.node, final.keys
+        final_brownout, final_intermittent = final.brownout, final.intermittent
+        tel_delta = final.telemetry
+
+        aggs = _fleet_aggregates(traces, alive_t, labels, per_node_labels,
+                                 intermittent, slot0, tasks=tasks, task=task)
+        out = {
+            "decisions": traces["decision"],                      # (S, N)
+            "payload_bytes": traces["payload"],                   # (S, N)
+            "stored_uj": traces["stored"],                        # (S, N)
+            "k_trace": traces["k"],                               # (S, N)
+            "logits": traces["logits"],                           # (S, N, L)
+            "preds": jnp.argmax(traces["logits"], axis=-1),       # (S, N)
+            "alive": traces["alive"],                             # (S, N)
+            "brownout": traces["brownout"],                       # (S, N)
+            "bytes_on_wire": aggs["bytes_on_wire"],
+            "bytes_on_wire_i32": aggs["bytes_on_wire_i32"],
+            "decision_histogram": aggs["decision_histogram"],
+            "completed": aggs["completed"],
+            "alive_slots": aggs["alive_slots"],
+            "brownout_slots": aggs["brownout_slots"],
+            "brownout_events": aggs["brownout_events"],
+            "completed_frac": aggs["completed"]
+                / jnp.maximum(aggs["alive_slots"], 1),
+            "raw_bytes_per_window": jnp.asarray(
+                float(raw_payload_bytes(t)) * windows.shape[-1], jnp.float32),
+            "final_state": final_state,
+            "final_keys": final_keys,
+            "final_brownout": final_brownout,
+        }
+        if tel_spec is not None:
+            out["telemetry"] = metrics_merge(tel_spec, telemetry_state0,
+                                             tel_delta)
+            out["telemetry_spec"] = tel_spec
+        if intermittent is not None:
+            out.update({
+                "it_emit": traces["it_emit"],                     # (S, N)
+                "it_label": traces["it_label"],                   # (S, N)
+                "it_conf": traces["it_conf"],                     # (S, N)
+                "it_src": traces["it_src"],                       # (S, N)
+                "it_stage": traces["it_stage"],                   # (S, N)
+                "it_full": aggs["it_full"],
+                "it_early": aggs["it_early"],
+                "final_intermittent": final_intermittent,
+            })
         if labels is not None:
-            out["correct_by_task"] = aggs["correct_by_task"]
-            out["accuracy_by_task"] = (
-                aggs["correct_by_task"]
-                / jnp.maximum(aggs["completed_by_task"], 1))
-    return out
+            out["correct"] = aggs["correct"]
+            out["fleet_accuracy"] = (aggs["correct"]
+                                     / jnp.maximum(aggs["completed"], 1))
+            if intermittent is not None:
+                out["correct_ladder"] = aggs["correct_ladder"]
+                out["it_correct_full"] = aggs["it_correct_full"]
+                out["it_correct_early"] = aggs["it_correct_early"]
+        if task is not None:
+            out["task_names"] = task.names
+            out["tasks"] = tasks
+            out["completed_by_task"] = aggs["completed_by_task"]
+            out["deadline_miss_by_task"] = aggs["deadline_miss_by_task"]
+            if labels is not None:
+                out["correct_by_task"] = aggs["correct_by_task"]
+                out["accuracy_by_task"] = (
+                    aggs["correct_by_task"]
+                    / jnp.maximum(aggs["completed_by_task"], 1))
+        return out
 
 
 def seeker_fleet_simulate_sharded(
@@ -1266,143 +1284,149 @@ def seeker_fleet_simulate_sharded(
     ``labels`` is given, ``padded_nodes`` (python int), ``node_axes``
     (python tuple of mesh axis names).
     """
-    costs = costs or EnergyCosts()
-    key = key if key is not None else jax.random.PRNGKey(0)
-    if mesh is None:
-        mesh = make_mesh_compat((jax.device_count(),), ("data",))
-    axis_names, quantum = node_mesh_axes(mesh)
-    if not axis_names:
-        raise ValueError(
-            f"mesh {mesh.axis_names} has none of the FLEET_RULES node axes")
+    with obs_trace.span("fleet.prep", cat="fleet"):
+        costs = costs or EnergyCosts()
+        key = key if key is not None else jax.random.PRNGKey(0)
+        if mesh is None:
+            mesh = make_mesh_compat((jax.device_count(),), ("data",))
+        axis_names, quantum = node_mesh_axes(mesh)
+        if not axis_names:
+            raise ValueError(
+                f"mesh {mesh.axis_names} has none of the FLEET_RULES node "
+                f"axes")
 
-    n, s = harvest.shape
-    assert windows.ndim in (3, 4), f"windows must be (S,T,C) or (N,S,T,C), got {windows.shape}"
-    shared_stream = windows.ndim == 3
-    pad = (-n) % quantum
-    if shared_stream:
-        assert windows.shape[0] == s, (windows.shape, s)
-        xs_windows = windows                                  # (S, T, C)
-    else:
-        assert windows.shape[:2] == (n, s), (windows.shape, n, s)
-        xs_windows = jnp.moveaxis(windows, 0, 1)              # (S, N, T, C)
-        if pad:   # inert nodes see all-zero windows (corr 0, masked anyway)
-            xs_windows = jnp.pad(xs_windows,
-                                 ((0, 0), (0, pad)) + ((0, 0),) * 2)
-    t = windows.shape[-2]
-    labels, per_node_labels = _resolve_labels(labels, s, n, shared_stream)
+        n, s = harvest.shape
+        assert windows.ndim in (3, 4), \
+            f"windows must be (S,T,C) or (N,S,T,C), got {windows.shape}"
+        shared_stream = windows.ndim == 3
+        pad = (-n) % quantum
+        if shared_stream:
+            assert windows.shape[0] == s, (windows.shape, s)
+            xs_windows = windows                              # (S, T, C)
+        else:
+            assert windows.shape[:2] == (n, s), (windows.shape, n, s)
+            xs_windows = jnp.moveaxis(windows, 0, 1)          # (S, N, T, C)
+            if pad:   # inert nodes see all-zero windows (corr 0, masked)
+                xs_windows = jnp.pad(xs_windows,
+                                     ((0, 0), (0, pad)) + ((0, 0),) * 2)
+        t = windows.shape[-2]
+        labels, per_node_labels = _resolve_labels(labels, s, n, shared_stream)
 
-    state_full = _stack_pad_state(state0, n, pad, predictor_window,
-                                  initial_uj)
-    keys0 = jax.vmap(lambda i: jax.random.fold_in(key, i))(
-        jnp.arange(n + pad))
-    if node_keys is not None:        # resume: real nodes continue their
-        keys0 = keys0.at[:n].set(node_keys)     # streams, pad keys inert
-    harvest_t = jnp.pad(harvest, ((0, pad), (0, 0))).T        # (S, N+pad)
-    # churn trace, padding nodes permanently dead (their ladder never runs)
-    alive_t = jnp.pad(_resolve_alive(alive, n, s),
-                      ((0, pad), (0, 0))).T                   # (S, N+pad)
-    mask = jnp.arange(n + pad) < n
-    if labels is None:
-        labels_arr = jnp.zeros((s,), jnp.int32)
-    elif per_node_labels:            # pad like harvest: inert nodes' track
-        labels_arr = jnp.pad(labels, ((0, 0), (0, pad)))      # (S, N+pad)
-    else:
-        labels_arr = labels
+        state_full = _stack_pad_state(state0, n, pad, predictor_window,
+                                      initial_uj)
+        keys0 = jax.vmap(lambda i: jax.random.fold_in(key, i))(
+            jnp.arange(n + pad))
+        if node_keys is not None:        # resume: real nodes continue their
+            keys0 = keys0.at[:n].set(node_keys)     # streams, pad keys inert
+        harvest_t = jnp.pad(harvest, ((0, pad), (0, 0))).T        # (S, N+pad)
+        # churn trace, padding nodes permanently dead (their ladder never runs)
+        alive_t = jnp.pad(_resolve_alive(alive, n, s),
+                          ((0, pad), (0, 0))).T                   # (S, N+pad)
+        mask = jnp.arange(n + pad) < n
+        if labels is None:
+            labels_arr = jnp.zeros((s,), jnp.int32)
+        elif per_node_labels:            # pad like harvest: inert nodes' track
+            labels_arr = jnp.pad(labels, ((0, 0), (0, pad)))      # (S, N+pad)
+        else:
+            labels_arr = labels
 
-    # brown-out flag, padding nodes forced awake: they are exogenously dead
-    # (frozen flag), so they can never brown "in" nor trickle back out
-    browned0 = jnp.pad(
-        _resolve_brownout0(brownout_state0, state_full, brownout, n),
-        (0, pad))
-    _validate_intermittent_args(intermittent, intermittent_state0,
-                                aux_params, n)
-    tasks, task = _resolve_tasks(tasks, task, n)
-    host_params = _resolve_task_host(task, host_params)
-    if tasks is not None and pad:   # padding nodes run task 0, masked out
-        tasks = jnp.pad(tasks, (0, pad))
-    tel_spec = _resolve_telemetry(telemetry, intermittent, task)
-    run_fn = _build_fleet_run_sharded(
-        mesh, axis_names, har_cfg, costs, quant_bits, k_max, m_samples,
-        corr_threshold, shared_stream, per_node_labels, node_block,
-        brownout, donate, intermittent, tel_spec, task)
-    it0 = None
-    if intermittent is not None:
-        it0 = (intermittent_state0 if intermittent_state0 is not None
-               else intermittent_fleet_init(n, har_cfg))
-        if pad:   # inert lane rows for padding nodes (never engage: dead)
-            filler = intermittent_fleet_init(pad, har_cfg)
-            it0 = jax.tree_util.tree_map(
-                lambda a, b: jnp.concatenate([a, b], axis=0), it0, filler)
-    xs_slots = jnp.arange(slot0, slot0 + s, dtype=jnp.int32)
-    traces, final, aggs = run_fn(
-        state_full, keys0, browned0, it0, tasks, xs_windows, harvest_t,
-        alive_t, xs_slots, mask, labels_arr, signatures, qdnn_params,
-        host_params, gen_params, aac_table, aux_params)
-    final_state, final_keys = final.node, final.keys
-    final_brownout, final_intermittent = final.brownout, final.intermittent
-    tel_delta = final.telemetry
-
-    out = {
-        "decisions": traces["decision"][:, :n],               # (S, N)
-        "payload_bytes": traces["payload"][:, :n],            # (S, N)
-        "stored_uj": traces["stored"][:, :n],                 # (S, N)
-        "k_trace": traces["k"][:, :n],                        # (S, N)
-        "logits": traces["logits"][:, :n],                    # (S, N, L)
-        "preds": jnp.argmax(traces["logits"][:, :n], axis=-1),
-        "alive": traces["alive"][:, :n],                      # (S, N)
-        "brownout": traces["brownout"][:, :n],                # (S, N)
-        "bytes_on_wire": aggs["bytes_on_wire"],
-        "bytes_on_wire_i32": aggs["bytes_on_wire_i32"],
-        "decision_histogram": aggs["decision_histogram"],
-        "completed": aggs["completed"],
-        "alive_slots": aggs["alive_slots"],
-        "brownout_slots": aggs["brownout_slots"],
-        "brownout_events": aggs["brownout_events"],
-        "completed_frac": aggs["completed"]
-            / jnp.maximum(aggs["alive_slots"], 1),
-        "raw_bytes_per_window": jnp.asarray(
-            float(raw_payload_bytes(t)) * windows.shape[-1], jnp.float32),
-        "final_state": jax.tree_util.tree_map(lambda a: a[:n], final_state),
-        "final_keys": final_keys[:n],
-        "final_brownout": final_brownout[:n],
-        "padded_nodes": pad,
-        "node_axes": axis_names,
-    }
-    if tel_spec is not None:
-        out["telemetry"] = metrics_merge(tel_spec, telemetry_state0,
-                                         tel_delta)
-        out["telemetry_spec"] = tel_spec
-    if intermittent is not None:
-        out.update({
-            "it_emit": traces["it_emit"][:, :n],              # (S, N)
-            "it_label": traces["it_label"][:, :n],            # (S, N)
-            "it_conf": traces["it_conf"][:, :n],              # (S, N)
-            "it_src": traces["it_src"][:, :n],                # (S, N)
-            "it_stage": traces["it_stage"][:, :n],            # (S, N)
-            "it_full": aggs["it_full"],
-            "it_early": aggs["it_early"],
-            "final_intermittent": jax.tree_util.tree_map(
-                lambda a: a[:n], final_intermittent),
-        })
-    if labels is not None:
-        out["correct"] = aggs["correct"]
-        out["fleet_accuracy"] = (aggs["correct"]
-                                 / jnp.maximum(aggs["completed"], 1))
+        # brown-out flag, padding nodes forced awake: they are exogenously dead
+        # (frozen flag), so they can never brown "in" nor trickle back out
+        browned0 = jnp.pad(
+            _resolve_brownout0(brownout_state0, state_full, brownout, n),
+            (0, pad))
+        _validate_intermittent_args(intermittent, intermittent_state0,
+                                    aux_params, n)
+        tasks, task = _resolve_tasks(tasks, task, n)
+        host_params = _resolve_task_host(task, host_params)
+        if tasks is not None and pad:   # padding nodes run task 0, masked out
+            tasks = jnp.pad(tasks, (0, pad))
+        tel_spec = _resolve_telemetry(telemetry, intermittent, task)
+        run_fn = _build_fleet_run_sharded(
+            mesh, axis_names, har_cfg, costs, quant_bits, k_max, m_samples,
+            corr_threshold, shared_stream, per_node_labels, node_block,
+            brownout, donate, intermittent, tel_spec, task)
+        it0 = None
         if intermittent is not None:
-            out["correct_ladder"] = aggs["correct_ladder"]
-            out["it_correct_full"] = aggs["it_correct_full"]
-            out["it_correct_early"] = aggs["it_correct_early"]
-    if task is not None:
-        out["task_names"] = task.names
-        out["tasks"] = tasks[:n]
-        out["completed_by_task"] = aggs["completed_by_task"]
-        out["deadline_miss_by_task"] = aggs["deadline_miss_by_task"]
+            it0 = (intermittent_state0 if intermittent_state0 is not None
+                   else intermittent_fleet_init(n, har_cfg))
+            if pad:   # inert lane rows for padding nodes (never engage: dead)
+                filler = intermittent_fleet_init(pad, har_cfg)
+                it0 = jax.tree_util.tree_map(
+                    lambda a, b: jnp.concatenate([a, b], axis=0), it0, filler)
+        xs_slots = jnp.arange(slot0, slot0 + s, dtype=jnp.int32)
+    with obs_trace.span("fleet.run", cat="fleet"):
+        traces, final, aggs = run_fn(
+            state_full, keys0, browned0, it0, tasks, xs_windows, harvest_t,
+            alive_t, xs_slots, mask, labels_arr, signatures, qdnn_params,
+            host_params, gen_params, aac_table, aux_params)
+    with obs_trace.span("fleet.aggregates", cat="fleet"):
+        final_state, final_keys = final.node, final.keys
+        final_brownout, final_intermittent = final.brownout, final.intermittent
+        tel_delta = final.telemetry
+
+        out = {
+            "decisions": traces["decision"][:, :n],               # (S, N)
+            "payload_bytes": traces["payload"][:, :n],            # (S, N)
+            "stored_uj": traces["stored"][:, :n],                 # (S, N)
+            "k_trace": traces["k"][:, :n],                        # (S, N)
+            "logits": traces["logits"][:, :n],                    # (S, N, L)
+            "preds": jnp.argmax(traces["logits"][:, :n], axis=-1),
+            "alive": traces["alive"][:, :n],                      # (S, N)
+            "brownout": traces["brownout"][:, :n],                # (S, N)
+            "bytes_on_wire": aggs["bytes_on_wire"],
+            "bytes_on_wire_i32": aggs["bytes_on_wire_i32"],
+            "decision_histogram": aggs["decision_histogram"],
+            "completed": aggs["completed"],
+            "alive_slots": aggs["alive_slots"],
+            "brownout_slots": aggs["brownout_slots"],
+            "brownout_events": aggs["brownout_events"],
+            "completed_frac": aggs["completed"]
+                / jnp.maximum(aggs["alive_slots"], 1),
+            "raw_bytes_per_window": jnp.asarray(
+                float(raw_payload_bytes(t)) * windows.shape[-1], jnp.float32),
+            "final_state": jax.tree_util.tree_map(lambda a: a[:n],
+                                                  final_state),
+            "final_keys": final_keys[:n],
+            "final_brownout": final_brownout[:n],
+            "padded_nodes": pad,
+            "node_axes": axis_names,
+        }
+        if tel_spec is not None:
+            out["telemetry"] = metrics_merge(tel_spec, telemetry_state0,
+                                             tel_delta)
+            out["telemetry_spec"] = tel_spec
+        if intermittent is not None:
+            out.update({
+                "it_emit": traces["it_emit"][:, :n],              # (S, N)
+                "it_label": traces["it_label"][:, :n],            # (S, N)
+                "it_conf": traces["it_conf"][:, :n],              # (S, N)
+                "it_src": traces["it_src"][:, :n],                # (S, N)
+                "it_stage": traces["it_stage"][:, :n],            # (S, N)
+                "it_full": aggs["it_full"],
+                "it_early": aggs["it_early"],
+                "final_intermittent": jax.tree_util.tree_map(
+                    lambda a: a[:n], final_intermittent),
+            })
         if labels is not None:
-            out["correct_by_task"] = aggs["correct_by_task"]
-            out["accuracy_by_task"] = (
-                aggs["correct_by_task"]
-                / jnp.maximum(aggs["completed_by_task"], 1))
-    return out
+            out["correct"] = aggs["correct"]
+            out["fleet_accuracy"] = (aggs["correct"]
+                                     / jnp.maximum(aggs["completed"], 1))
+            if intermittent is not None:
+                out["correct_ladder"] = aggs["correct_ladder"]
+                out["it_correct_full"] = aggs["it_correct_full"]
+                out["it_correct_early"] = aggs["it_correct_early"]
+        if task is not None:
+            out["task_names"] = task.names
+            out["tasks"] = tasks[:n]
+            out["completed_by_task"] = aggs["completed_by_task"]
+            out["deadline_miss_by_task"] = aggs["deadline_miss_by_task"]
+            if labels is not None:
+                out["correct_by_task"] = aggs["correct_by_task"]
+                out["accuracy_by_task"] = (
+                    aggs["correct_by_task"]
+                    / jnp.maximum(aggs["completed_by_task"], 1))
+        return out
 
 
 def seeker_fleet_simulate_streamed(

@@ -46,6 +46,7 @@ def _run(code: str, devices: int = 8, timeout: int = 560) -> str:
 
 
 _EQUIV_CODE = """
+import re
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.configs.seeker_har import HAR
@@ -120,6 +121,15 @@ for k in ("decisions", "payload_bytes", "stored_uj", "k_trace"):
 np.testing.assert_allclose(np.asarray(sh["logits"]), np.asarray(ref["logits"]),
                            rtol=1e-5, atol=1e-5)
 print("default node_block OK")
+
+# the psums of the fleet aggregates carry the collectives scope into the
+# compiled program, where a device trace reads it
+hlo = jax.jit(lambda w, h: seeker_fleet_simulate_sharded(
+    w, h, signatures=sigs, qdnn_params=params, host_params=params,
+    gen_params=gen, har_cfg=HAR, mesh=mesh, donate=False)[
+        "decision_histogram"]).lower(wins, harvest).compile().as_text()
+assert re.search(r"all-reduce.*seeker[.]collectives", hlo), "no scoped psum"
+print("collectives scope OK")
 print("OK")
 """
 

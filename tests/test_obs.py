@@ -9,8 +9,10 @@ owns the subprocess mesh idiom.
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +39,7 @@ from repro.serving import (encode_wire_coresets, fleet_telemetry_spec,
                            seeker_fleet_simulate_streamed, wire_bytes_exact)
 
 S, N = 6, 5
+ROOT_DIR = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -384,6 +387,80 @@ def test_trace_export_is_chrome_trace_json(tmp_path):
     assert sp["dur"] >= 0 and {"ts", "pid", "tid"} <= sp.keys()
     # the instant fired inside the span's interval
     assert sp["ts"] <= kinds["i"]["ts"] <= sp["ts"] + sp["dur"]
+
+
+# ---------------------------------------------------------------------------
+# Layer scopes and profiler spans
+# ---------------------------------------------------------------------------
+
+ONE_CHIP_SCOPES = sorted(set(trace.SCOPES) - {"seeker.collectives"})
+
+
+@pytest.fixture(scope="module")
+def fleet_hlo():
+    """The compiled one-chip fleet engine at N=4, S=2."""
+    key = jax.random.PRNGKey(1)
+    params = har_init(key, HAR)
+    gen = init_generator(key, HAR.window, HAR.channels)
+    wins, _ = har_stream(key, 2)
+    harvest = fleet_harvest_traces(key, 4, 2)
+    sigs = class_signatures()
+    run = jax.jit(lambda w, h: seeker_fleet_simulate(
+        w, h, signatures=sigs, qdnn_params=params, host_params=params,
+        gen_params=gen, har_cfg=HAR, donate=False))
+    return run.lower(wins, harvest).compile().as_text()
+
+
+@pytest.mark.parametrize("name", ONE_CHIP_SCOPES)
+def test_fleet_layer_scope_in_op_metadata(fleet_hlo, name):
+    op_names = re.findall(r'op_name="([^"]*)"', fleet_hlo)
+    assert any(re.search(rf"(?<![\w.]){re.escape(name)}(?![\w.])", o)
+               for o in op_names), name
+
+
+def test_scope_refuses_unregistered_name():
+    with pytest.raises(ValueError, match="not registered"):
+        trace.scope("seeker.unregistered")
+    with trace.scope("seeker.corr"):
+        pass
+
+
+def test_scope_names_in_use_are_registered():
+    """Every ``scope(...)`` call of the program names a registered scope,
+    every registered scope is used, and every scope name the benchmark's
+    code mentions is registered."""
+    used = set()
+    for path in (ROOT_DIR / "src" / "repro").rglob("*.py"):
+        used |= set(re.findall(r'\bscope\("([^"]+)"\)', path.read_text()))
+    assert used == set(trace.SCOPES)
+    named = set()
+    for path in (ROOT_DIR / "bench").rglob("*.py"):
+        named |= set(re.findall(r'"(seeker\.[A-Za-z_]+)"',
+                                path.read_text()))
+    assert named <= set(trace.SCOPES)
+
+
+def test_span_lands_on_profiler_host_plane(tmp_path):
+    """A span is a profiler annotation whether or not the wall-clock buffer
+    is on; with the buffer off it records no Chrome event."""
+    was = trace.enabled()
+    trace.enable(False)
+    trace.clear()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("obs.profiled_span"):
+                jnp.arange(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        assert trace.events() == []
+    finally:
+        trace.enable(was)
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    host = [e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    assert "obs.profiled_span" in host
 
 
 # ---------------------------------------------------------------------------
