@@ -25,11 +25,14 @@ are validated against these references.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 __all__ = [
     "ClusterCoreset",
@@ -103,15 +106,56 @@ def points_from_window(window: jnp.ndarray, time_scale: float | None = None) -> 
     return jnp.concatenate([tcoord[:, None], window], axis=-1)
 
 
+def _pick(mask: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` at the one True of ``mask`` along the last axis, exactly.
+
+    A masked max, not a masked sum, so a picked ``-0.0`` keeps its sign.  The
+    compare-and-select fuses into the reduction; the indexing it replaces
+    becomes a per-row gather under vmap, which a TPU runs orders of
+    magnitude below its memory bandwidth."""
+    return jnp.max(jnp.where(mask, x, -jnp.inf), axis=-1)
+
+
+# one compiled unit, as jnp.interp is: eager callers do not dispatch op by op
+@functools.partial(jax.jit, static_argnames="t")
 def window_from_points(points: jnp.ndarray, t: int) -> jnp.ndarray:
-    """Inverse of :func:`points_from_window`: sort by the time coordinate and
-    resample onto a regular (T, C) grid by linear interpolation in time."""
-    order = jnp.argsort(points[:, 0])
-    pts = points[order]
-    src = (pts[:, 0] - pts[0, 0]) / jnp.maximum(pts[-1, 0] - pts[0, 0], 1e-9)
+    """Inverse of :func:`points_from_window`: order the points by their time
+    coordinate and resample onto a regular (T, C) grid by linear
+    interpolation in time.
+
+    Bitwise the stable ``argsort`` + ``jnp.interp`` of the points, written
+    without a sort, a search or a gather: each point's sorted position is its
+    stable rank from an (n, n) comparison, each grid point's bracket is a
+    count, and the bracket's ends are picked by :func:`_pick`."""
+    n = points.shape[0]
+    x = lax.index_in_dim(points, 0, axis=1, keepdims=False)
+    idx = jnp.arange(n)
+    # rank_j = #{i: x_i < x_j} + #{i < j: x_i == x_j}: argsort's stable order
+    before = (x[:, None] < x[None, :]) | (
+        (x[:, None] == x[None, :]) & (idx[:, None] < idx[None, :]))
+    rank = jnp.sum(before, axis=0)
+    first, last = rank == 0, rank == n - 1
+    x0 = _pick(first, x)
+    src = (x - x0) / jnp.maximum(_pick(last, x) - x0, 1e-9)
     grid = jnp.linspace(0.0, 1.0, t)
-    cols = [jnp.interp(grid, src, pts[:, 1 + c])
-            for c in range(points.shape[1] - 1)]
+    # jnp.interp term by term; i is searchsorted(src, grid, side="right")
+    i = jnp.clip(jnp.sum(src[None, :] <= grid[:, None], axis=1), 1, n - 1)
+    hi = rank[None, :] == i[:, None]                          # (t, n)
+    lo = rank[None, :] == i[:, None] - 1
+    xp_lo = _pick(lo, src)
+    dx = _pick(hi, src) - xp_lo
+    delta = grid - xp_lo
+    dx0 = jnp.abs(dx) <= np.spacing(np.finfo(src.dtype).eps)
+    below = grid < _pick(first, src)
+    above = grid > _pick(last, src)
+    cols = []
+    for c in range(points.shape[1] - 1):
+        fp = lax.index_in_dim(points, 1 + c, axis=1, keepdims=False)
+        fp_lo = _pick(lo, fp)
+        df = _pick(hi, fp) - fp_lo
+        f = jnp.where(dx0, fp_lo, fp_lo + (delta / jnp.where(dx0, 1, dx)) * df)
+        f = jnp.where(below, _pick(first, fp), f)
+        cols.append(jnp.where(above, _pick(last, fp), f))
     return jnp.stack(cols, axis=-1)
 
 

@@ -19,12 +19,14 @@ serve step.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from .coreset import ClusterCoreset, SamplingCoreset, window_from_points
+from .coreset import ClusterCoreset, SamplingCoreset, _pick, window_from_points
 
 __all__ = [
     "recover_cluster_points",
@@ -75,15 +77,22 @@ def recover_cluster_points(cs: ClusterCoreset, key: jax.Array,
     # slot i belongs to cluster c where cum_counts[c-1] <= floor(i*total/n) < cum_counts[c]
     cum = jnp.cumsum(cs.counts)
     slot_pos = (jnp.arange(n_points) * total) // n_points      # (n_points,) in [0, total)
-    slot_cluster = jnp.searchsorted(cum, slot_pos, side="right")
+    # searchsorted(cum, slot_pos, side="right") as a count, and the
+    # cluster's parameters by an exact one-hot pick: no per-row gather
+    slot_cluster = jnp.sum(cum[None, :] <= slot_pos[:, None], axis=1)
     slot_cluster = jnp.clip(slot_cluster, 0, k - 1)
     mask = jnp.arange(n_points) < total
+    onehot = slot_cluster[:, None] == jnp.arange(k)              # (n_points, k)
 
     offs = _uniform_in_ball(key, n_points, d, dtype=cs.centers.dtype)
-    pts = cs.centers[slot_cluster] + offs * cs.radii[slot_cluster][:, None]
+    centers = _pick(onehot[:, None, :], cs.centers.T[None])      # (n_points, d)
+    radii = _pick(onehot, cs.radii[None, :])
+    pts = centers + offs * radii[:, None]
     return pts, mask
 
 
+# one compiled unit: eager callers do not dispatch op by op
+@functools.partial(jax.jit, static_argnames="t")
 def recover_cluster_window(cs: ClusterCoreset, key: jax.Array, t: int) -> jnp.ndarray:
     """Full pipeline: coreset -> synthesized points -> regular (T, C) window.
 
@@ -98,7 +107,8 @@ def recover_cluster_window(cs: ClusterCoreset, key: jax.Array, t: int) -> jnp.nd
         def one(centers, radii, counts, kk):
             sub = ClusterCoreset(centers, radii, counts)
             pts, _ = recover_cluster_points(sub, kk, n_points=t)
-            return window_from_points(pts, t)[:, 0]
+            return lax.index_in_dim(window_from_points(pts, t), 0, axis=1,
+                                    keepdims=False)
 
         cols = jax.vmap(one)(cs.centers, cs.radii, cs.counts, keys)
         return cols.T                              # (T, C)
