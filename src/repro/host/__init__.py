@@ -15,8 +15,8 @@ from .cache import (  # noqa: F401
 )
 from .server import (  # noqa: F401
     CLUSTER_KIND, SAMPLING_KIND, HostPayload, HostServeConfig,
-    HostServerState, SlotOutput, cluster_entries, host_ensemble,
-    host_payload_example, host_serve_slot, host_serve_trace,
+    HostServerState, SlotOutput, cluster_entries, fleet_slot_entries,
+    host_ensemble, host_payload_example, host_serve_slot, host_serve_trace,
     host_server_init, host_server_init_stacked, host_server_stats,
     host_telemetry_spec, recover_infer_batch, sampling_entries,
     serve_fleet_payloads, serve_trace_count,

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.coreset import ClusterCoreset, SamplingCoreset
+from ..core.decision import D3_CLUSTER, D4_SAMPLING
 from ..core.recovery import (GeneratorParams, recover_cluster_window,
                              recover_sampling_window)
 from ..models.har import har_apply
@@ -48,7 +49,8 @@ from ..obs import (MetricsSpec, compile_event, counter, counter_add,
 from ..obs import trace as obs_trace
 from ..obs.compile_guard import compile_key_counts
 from ..serving.edge_host import (WirePayload, WireSamplePayload,
-                                 decode_wire_coresets, decode_wire_samples)
+                                 decode_wire_coresets, decode_wire_samples,
+                                 encode_wire_coresets, encode_wire_samples)
 from .cache import (RecoveryCache, cache_init, cache_insert_batch,
                     cache_lookup_batch, cache_stats, payload_signature)
 from .queue import (PayloadQueue, queue_init, queue_occupancy,
@@ -57,6 +59,7 @@ from .scheduler import batch_wait_slots, edf_pop_batch
 
 __all__ = ["HostServeConfig", "HostPayload", "HostServerState", "SlotOutput",
            "host_payload_example", "cluster_entries", "sampling_entries",
+           "fleet_slot_entries",
            "host_server_init", "host_server_init_stacked", "host_serve_slot",
            "host_serve_trace", "host_telemetry_spec", "serve_fleet_payloads",
            "recover_infer_batch", "host_server_stats", "host_ensemble",
@@ -230,6 +233,34 @@ def sampling_entries(swire: WireSamplePayload, k: int,
         task=_entry_tasks(tasks, b))
 
 
+def fleet_slot_entries(coresets: dict, decisions: jnp.ndarray,
+                       alive: jnp.ndarray
+                       ) -> tuple[HostPayload, jnp.ndarray, jnp.ndarray]:
+    """One fleet slot's payloads as the host's fixed-width ingest lane.
+
+    ``coresets`` is one slot of the fleet engine's ``res["coresets"]``
+    (leaves with leading node axis N), ``decisions`` and ``alive`` the
+    slot's (N,) decision and alive traces.  Row ``i`` is node ``i``'s
+    payload through the wire encoders: a D3 node's cluster coreset as a
+    ``CLUSTER_KIND`` entry, a D4 node's samples as a ``SAMPLING_KIND``
+    entry.  Returns ``(entries, node_ids, mask)`` for
+    :func:`host_serve_slot`; ``mask`` is alive and D3-or-D4, so nodes that
+    sent nothing, or only a label, never enqueue."""
+    k = coresets["centers"].shape[-2]
+    m = coresets["samp_idx"].shape[-1]
+    cluster = cluster_entries(encode_wire_coresets(
+        coresets["centers"], coresets["radii"], coresets["counts"]), m)
+    sampling = sampling_entries(encode_wire_samples(
+        coresets["samp_idx"], coresets["samp_vals"], coresets["samp_mean"],
+        coresets["samp_var"]), k)
+    is_d4 = decisions == D4_SAMPLING
+    entries = jax.tree_util.tree_map(
+        lambda c, s: jnp.where(is_d4.reshape((-1,) + (1,) * (c.ndim - 1)),
+                               s, c), cluster, sampling)
+    mask = alive & ((decisions == D3_CLUSTER) | is_d4)
+    return entries, jnp.arange(decisions.shape[0], dtype=jnp.int32), mask
+
+
 @functools.lru_cache(maxsize=32)
 def _host_spec(qos_slots: int) -> MetricsSpec:
     # sojourn of a SERVED payload is 0..qos_slots (later pops expire first);
@@ -398,44 +429,23 @@ def serve_trace_count(cfg: HostServeConfig | None = None) -> int:
     return sum(counts.values())
 
 
-def _slot_body(cfg: HostServeConfig, state: HostServerState,
-               entries: HostPayload, node_ids: jnp.ndarray,
-               mask: jnp.ndarray, host_params: dict,
-               gen_params: GeneratorParams, base_key: jax.Array
-               ) -> tuple[HostServerState, SlotOutput]:
-    """One serve slot: ingest stamped arrivals, then run
-    ``cfg.batches_per_slot`` EDF microbatches through cache + recovery +
-    DNN.  Pure function of fixed-shape inputs."""
-    tel = host_telemetry_spec(cfg) if cfg.telemetry else None
-    metrics = state.metrics
-    if tel is not None and metrics is None:
-        raise ValueError(
-            "cfg.telemetry=True but the server state has no metrics lanes — "
-            "build the state with host_server_init(cfg) using the SAME "
-            "telemetry setting (the lanes are part of the resumable carry)")
-    arrival = jnp.broadcast_to(state.slot, node_ids.shape)
-    deadline = arrival + cfg.qos_slots
-    queue, _ = queue_push_batch(state.queue, entries, node_ids, arrival,
-                                deadline, mask)
-    if tel is not None:
-        metrics = counter_add(
-            tel, metrics, "host.drops_overflow",
-            queue.drops_overflow - state.queue.drops_overflow)
-
-    cache = state.cache
-    served, missed_total = state.served, state.deadline_misses
-    ens_l, ens_v = state.ensemble_logits, state.ensemble_votes
-    outs = []
-    for _ in range(cfg.batches_per_slot):
-        queue, batch, missed = edf_pop_batch(queue, cfg.batch_size,
-                                             now=state.slot)
+def _serve_batch(cfg: HostServeConfig, tel: MetricsSpec | None, now,
+                 carry: tuple, host_params: dict,
+                 gen_params: GeneratorParams, base_key: jax.Array
+                 ) -> tuple[tuple, SlotOutput]:
+    """One EDF microbatch through cache + recovery + DNN + ensemble.
+    ``carry`` = (queue, cache, served, deadline misses, ensemble logits,
+    ensemble votes, metrics)."""
+    queue, cache, served, missed_total, ens_l, ens_v, metrics = carry
+    with obs_trace.scope("host.edf"):
+        queue, batch, missed = edf_pop_batch(queue, cfg.batch_size, now=now)
         missed_total = missed_total + missed
         if tel is not None:
             # QoS observables at service time: queue sojourn of every row
             # served this batch (and its +1-slot end-to-end latency), with a
             # per-payload-class breakdown — the histograms the p50/p95/p99
             # extraction reads
-            sojourn = batch_wait_slots(batch, state.slot)
+            sojourn = batch_wait_slots(batch, now)
             is_cluster = batch.valid & (batch.payload.kind == CLUSTER_KIND)
             is_sampling = batch.valid & (batch.payload.kind == SAMPLING_KIND)
             metrics = hist_observe(tel, metrics, "host.sojourn_slots",
@@ -451,15 +461,18 @@ def _slot_body(cfg: HostServeConfig, state: HostServerState,
             metrics = counter_add(tel, metrics, "host.deadline_misses",
                                   missed)
 
+    with obs_trace.scope("host.cache"):
         sigs = jax.vmap(payload_signature)(batch.payload)        # (B, 2)
         hit, cached = cache_lookup_batch(cache, sigs, batch.valid)
-        keys = jax.vmap(
-            lambda s: jax.random.fold_in(jax.random.fold_in(base_key, s[0]),
-                                         s[1]))(sigs)
 
-        def compute(_):
+    def compute(_):
+        with obs_trace.scope("host.recover"):
+            keys = jax.vmap(
+                lambda s: jax.random.fold_in(
+                    jax.random.fold_in(base_key, s[0]), s[1]))(sigs)
             wins = _entry_windows(batch.payload, gen_params, keys, cfg.t,
                                   batch.valid)
+        with obs_trace.scope("host.dnn"):
             if cfg.n_tasks == 1:
                 return har_apply(host_params, wins)
             # mixed fleets: run every task's DNN over the batch at fixed
@@ -471,6 +484,7 @@ def _slot_body(cfg: HostServeConfig, state: HostServerState,
                            0, cfg.n_tasks - 1)
             return per_task[tid, jnp.arange(tid.shape[0])]
 
+    with obs_trace.scope("host.cache"):
         # a fully-memoized batch skips recovery + DNN (the host-side D0 skip)
         all_hit = jnp.all(hit | ~batch.valid)
         logits = jax.lax.cond(all_hit, lambda _: cached, compute, None)
@@ -481,11 +495,12 @@ def _slot_body(cfg: HostServeConfig, state: HostServerState,
         cache = cache._replace(
             hits=cache.hits + jnp.sum(hit.astype(jnp.int32)),
             misses=cache.misses + jnp.sum(fresh.astype(jnp.int32)))
-        served = served + jnp.sum(batch.valid.astype(jnp.int32))
         if tel is not None:
             metrics = counter_add(tel, metrics, "host.cache_hits", hit)
             metrics = counter_add(tel, metrics, "host.cache_misses", fresh)
 
+    with obs_trace.scope("host.ensemble"):
+        served = served + jnp.sum(batch.valid.astype(jnp.int32))
         # per-node ensemble: mean-logit sum + majority-vote histogram
         nid = jnp.clip(jnp.where(batch.valid, batch.node_id, 0),
                        0, cfg.n_nodes - 1)
@@ -495,19 +510,56 @@ def _slot_body(cfg: HostServeConfig, state: HostServerState,
                                 dtype=jnp.int32)
                  * batch.valid[:, None].astype(jnp.int32))
         ens_v = ens_v.at[nid].add(votes)
-        outs.append(SlotOutput(batch.node_id, logits, batch.deadline,
-                               hit, batch.valid))
+    return ((queue, cache, served, missed_total, ens_l, ens_v, metrics),
+            SlotOutput(batch.node_id, logits, batch.deadline, hit,
+                       batch.valid))
 
-    out = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0),
-                                 *outs)
+
+def _slot_body(cfg: HostServeConfig, state: HostServerState,
+               entries: HostPayload, node_ids: jnp.ndarray,
+               mask: jnp.ndarray, host_params: dict,
+               gen_params: GeneratorParams, base_key: jax.Array
+               ) -> tuple[HostServerState, SlotOutput]:
+    """One serve slot: ingest stamped arrivals, then run
+    ``cfg.batches_per_slot`` EDF microbatches through cache + recovery +
+    DNN.  The microbatches are one ``lax.scan``, so the program does not
+    grow with ``batches_per_slot``.  Pure function of fixed-shape
+    inputs."""
+    tel = host_telemetry_spec(cfg) if cfg.telemetry else None
+    metrics = state.metrics
+    if tel is not None and metrics is None:
+        raise ValueError(
+            "cfg.telemetry=True but the server state has no metrics lanes — "
+            "build the state with host_server_init(cfg) using the SAME "
+            "telemetry setting (the lanes are part of the resumable carry)")
+    with obs_trace.scope("host.ingest"):
+        arrival = jnp.broadcast_to(state.slot, node_ids.shape)
+        deadline = arrival + cfg.qos_slots
+        queue, _ = queue_push_batch(state.queue, entries, node_ids, arrival,
+                                    deadline, mask)
+        if tel is not None:
+            metrics = counter_add(
+                tel, metrics, "host.drops_overflow",
+                queue.drops_overflow - state.queue.drops_overflow)
+
+    carry = (queue, state.cache, state.served, state.deadline_misses,
+             state.ensemble_logits, state.ensemble_votes, metrics)
+    carry, outs = jax.lax.scan(
+        lambda c, _: _serve_batch(cfg, tel, state.slot, c, host_params,
+                                  gen_params, base_key),
+        carry, None, length=cfg.batches_per_slot)
+    queue, cache, served, missed_total, ens_l, ens_v, metrics = carry
+    out = jax.tree_util.tree_map(
+        lambda x: x.reshape((-1,) + x.shape[2:]), outs)
     if tel is not None:
-        # backlog level and age profile AFTER this slot's service — what is
-        # still waiting, and for how long it has waited
-        metrics = gauge_set(tel, metrics, "host.backlog",
-                            queue_occupancy(queue))
-        metrics = hist_observe(tel, metrics, "host.backlog_age_slots",
-                               queue_wait_slots(queue, state.slot),
-                               queue.valid)
+        with obs_trace.scope("host.edf"):
+            # backlog level and age profile AFTER this slot's service — what
+            # is still waiting, and for how long it has waited
+            metrics = gauge_set(tel, metrics, "host.backlog",
+                                queue_occupancy(queue))
+            metrics = hist_observe(tel, metrics, "host.backlog_age_slots",
+                                   queue_wait_slots(queue, state.slot),
+                                   queue.valid)
     new_state = HostServerState(queue, cache, state.slot + 1, served,
                                 missed_total, ens_l, ens_v, metrics)
     return new_state, out
@@ -551,11 +603,17 @@ def host_serve_slot(state: HostServerState, entries: HostPayload,
     ``entries`` leaves have leading axis A (the lane width — pad a churny
     slot's arrivals up to a FIXED A and mask the padding; a varying A would
     recompile).  Returns ``(state', SlotOutput)``; feed ``state'`` back in —
-    backlog, cache, clock and ensemble all carry over."""
-    _check_lane_width(cfg, entries.kind.shape[0])
-    run = _build_serve_slot(cfg, donate)
-    return run(state, entries, jnp.asarray(node_ids, jnp.int32),
-               jnp.asarray(mask, bool), host_params, gen_params, base_key)
+    backlog, cache, clock and ensemble all carry over.  The host spans
+    ``host.prep`` (argument checks and conversion) and ``host.run`` (the
+    compiled slot's dispatch) mark the call on the profiler's clock."""
+    with obs_trace.span("host.prep", cat="host"):
+        _check_lane_width(cfg, entries.kind.shape[0])
+        run = _build_serve_slot(cfg, donate)
+        node_ids = jnp.asarray(node_ids, jnp.int32)
+        mask = jnp.asarray(mask, bool)
+    with obs_trace.span("host.run", cat="host"):
+        return run(state, entries, node_ids, mask, host_params, gen_params,
+                   base_key)
 
 
 def host_serve_trace(state: HostServerState, entries: HostPayload,

@@ -1,14 +1,17 @@
 """Program spans on the profiler's clock, and the registry of scope names
-that label the fleet scan's layers in the device trace.
+that label the fleet scan's and the host serve slot's layers in the
+device trace.
 
 Spans (:func:`span`) mark host-side phases: fleet argument preparation,
-the scan's dispatch, the eager aggregates, streamed segments, benchmark
-phases.  Each span always enters ``jax.profiler.TraceAnnotation``, so
-under a profiler trace it lands on the host plane on the same clock as the
-device's ops, and costs one annotation otherwise.
+the scan's dispatch, the eager aggregates, streamed segments, the host
+tier's slot preparation and dispatch, benchmark phases.  Each span always
+enters ``jax.profiler.TraceAnnotation``, so under a profiler trace it
+lands on the host plane on the same clock as the device's ops, and costs
+one annotation otherwise.
 
-Scopes (:func:`scope`) name the layers of traced code: each enters
-``jax.named_scope`` with a name from :data:`SCOPES`, which XLA keeps in
+Scopes (:func:`scope`) name the layers of traced code, the fleet scan's
+in :data:`SCOPES` and the host serve slot's in :data:`HOST_SCOPES`: each
+enters ``jax.named_scope`` with a registered name, which XLA keeps in
 every op's metadata (the ``tf_op`` stat of a device op in the trace).
 ``bench/xplane.py`` reduces a trace to device seconds per scope.  Names
 are dotted, never ``/``: the name stack uses ``/`` between levels, and a
@@ -33,7 +36,7 @@ import time
 
 import jax
 
-__all__ = ["SCOPES", "scope", "enable", "enabled", "clear", "span",
+__all__ = ["SCOPES", "HOST_SCOPES", "scope", "enable", "enabled", "clear", "span",
            "instant", "events", "export_chrome_trace"]
 
 SCOPES = {
@@ -57,6 +60,21 @@ SCOPES = {
                           "aggregates and telemetry",
 }
 
+# the host tier's serve slot (repro.host.server), registered apart from the
+# fleet scan's layers: a fleet trace holds none of these
+HOST_SCOPES = {
+    "host.ingest": "the host's queue push of a slot's ingest lane and its "
+                   "overflow count",
+    "host.edf": "deadline expiry, the EDF microbatch pop and the QoS "
+                "histograms",
+    "host.cache": "payload signatures, the recovery-memo lookup and insert",
+    "host.recover": "per-payload recovery keys and the cluster or GAN "
+                    "recovery of a microbatch (_entry_windows)",
+    "host.dnn": "the host's full-precision DNN on a microbatch",
+    "host.ensemble": "the per-node mean-logit and vote ensemble",
+}
+_REGISTERED = {**SCOPES, **HOST_SCOPES}
+
 _LOCK = threading.Lock()
 _ENABLED = False
 _EVENTS: list[dict] = []
@@ -64,11 +82,12 @@ _T0_NS = time.perf_counter_ns()
 
 
 def scope(name: str):
-    """``jax.named_scope(name)`` for a name registered in :data:`SCOPES`;
-    called while tracing, so an unregistered name fails at trace time."""
-    if name not in SCOPES:
+    """``jax.named_scope(name)`` for a name registered in :data:`SCOPES` or
+    :data:`HOST_SCOPES`; called while tracing, so an unregistered name
+    fails at trace time."""
+    if name not in _REGISTERED:
         raise ValueError(f"scope {name!r} is not registered in "
-                         f"repro.obs.trace.SCOPES")
+                         f"repro.obs.trace.SCOPES or HOST_SCOPES")
     return jax.named_scope(name)
 
 

@@ -268,7 +268,8 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
                      brownout: BrownoutConfig | None,
                      intermittent: IntermittentConfig | None = None,
                      telemetry: MetricsSpec | None = None,
-                     task: TaskLaneConfig | None = None):
+                     task: TaskLaneConfig | None = None,
+                     coresets: bool = False):
     """One fleet time slot, shared VERBATIM by the single-device scan and the
     per-shard scan inside ``shard_map`` — the sharded engine sees exactly this
     computation on its local node tile.
@@ -290,7 +291,12 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
     ``node_block``).  The lane's state obeys the same ``keep()`` freeze as
     the rest of the carry — a browned-out or dead node's suspended
     activations survive untouched until it rejoins, which is exactly the
-    suspend-across-brown-out semantics."""
+    suspend-across-brown-out semantics.
+
+    ``coresets``: also emit each node's cluster coreset (after the AAC
+    mask) and sampling coreset as the slot computed them, under the trace
+    key ``"coresets"`` — the payloads a host tier takes in.  ``False``
+    keeps the step's program unchanged."""
 
     strict = brownout is not None or intermittent is not None
 
@@ -394,6 +400,12 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
             trace.update({"it_emit": lane.emit, "it_label": lane.emit_label,
                           "it_conf": lane.emit_conf, "it_src": lane.emit_src,
                           "it_stage": lane.emit_stage})
+        if coresets:
+            trace["coresets"] = {
+                "centers": out.coreset_centers, "radii": out.coreset_radii,
+                "counts": out.coreset_counts, "samp_idx": out.samp_idx,
+                "samp_vals": out.samp_vals, "samp_mean": out.samp_mean,
+                "samp_var": out.samp_var}
         return out.state, ks[:, 0], new_it, trace
 
     active = _active_lanes(intermittent, task, brownout)
@@ -507,6 +519,8 @@ def _make_fleet_step(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
                     "it_src": trace["it_src"],
                     "it_stage": trace["it_stage"],
                 })
+            if coresets:
+                out_trace["coresets"] = trace["coresets"]
             new_metrics = (None if telemetry is None else _update_fleet_lanes(
                 telemetry, metrics, out_trace, alive_t, active, tasks))
         return FleetCarry(node=new_state, keys=new_keys,
@@ -523,7 +537,8 @@ def _build_fleet_run(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
                      brownout: BrownoutConfig | None, donate: bool,
                      intermittent: IntermittentConfig | None = None,
                      telemetry: MetricsSpec | None = None,
-                     task: TaskLaneConfig | None = None):
+                     task: TaskLaneConfig | None = None,
+                     coresets: bool = False):
     """Compile-cached fleet scan, keyed on the static configuration.
 
     All arrays (params, signatures, windows, state) are jit *arguments*, so
@@ -552,7 +567,8 @@ def _build_fleet_run(har_cfg: HARConfig, costs: EnergyCosts, quant_bits: int,
         step = _make_fleet_step(har_cfg, costs, quant_bits, k_max,
                                 m_samples, corr_threshold, shared_stream,
                                 t, node_block, brownout, intermittent,
-                                telemetry=telemetry, task=task)
+                                telemetry=telemetry, task=task,
+                                coresets=coresets)
         carry0 = FleetCarry(
             node=state0, keys=keys0, brownout=browned0, intermittent=it0,
             telemetry=None if telemetry is None else metrics_init(telemetry))
@@ -980,7 +996,8 @@ def seeker_fleet_simulate(windows: jnp.ndarray, harvest: jnp.ndarray, *,
                           telemetry=None,
                           telemetry_state0: dict | None = None,
                           tasks: jnp.ndarray | None = None,
-                          task: TaskLaneConfig | None = None):
+                          task: TaskLaneConfig | None = None,
+                          coresets: bool = False):
     """Simulate N independent Seeker nodes over S time slots in one scan.
 
     Args:
@@ -1070,6 +1087,13 @@ def seeker_fleet_simulate(windows: jnp.ndarray, harvest: jnp.ndarray, *,
             ``accuracy_by_task`` with labels) to the aggregates.  ``None``
             (with ``tasks=None``) keeps the engine bitwise-identical to
             the homogeneous fleet.
+        coresets: also return every node-slot's coresets as the scan
+            computed them, under ``res["coresets"]``: ``centers`` (S, N, C,
+            k, 2), ``radii`` and ``counts`` (S, N, C, k) after the AAC
+            mask, and ``samp_idx`` (S, N, m), ``samp_vals`` (S, N, m, C),
+            ``samp_mean`` and ``samp_var`` (S, N, C) — what a D3 or D4 node
+            sends, for :func:`repro.host.fleet_slot_entries`.  ``False``
+            (default) leaves the engine's program unchanged.
 
     Returns a dict of per-node traces, time-major:
         ``decisions``/``payload_bytes``/``stored_uj``/``k_trace``: (S, N),
@@ -1126,7 +1150,7 @@ def seeker_fleet_simulate(windows: jnp.ndarray, harvest: jnp.ndarray, *,
         run_fn = _build_fleet_run(har_cfg, costs, quant_bits, k_max, m_samples,
                                   corr_threshold, shared_stream, node_block,
                                   brownout, donate, intermittent, tel_spec,
-                                  task)
+                                  task, coresets)
         it0 = None
         if intermittent is not None:
             it0 = (intermittent_state0 if intermittent_state0 is not None
@@ -1169,6 +1193,8 @@ def seeker_fleet_simulate(windows: jnp.ndarray, harvest: jnp.ndarray, *,
             "final_keys": final_keys,
             "final_brownout": final_brownout,
         }
+        if coresets:
+            out["coresets"] = traces["coresets"]
         if tel_spec is not None:
             out["telemetry"] = metrics_merge(tel_spec, telemetry_state0,
                                              tel_delta)

@@ -21,7 +21,7 @@ import pytest
 
 from repro.configs.seeker_har import HAR
 from repro.core import DEFER, BrownoutConfig, fleet_harvest_traces
-from repro.core.coreset import channel_cluster_coresets
+from repro.core.coreset import channel_cluster_coresets, importance_coreset
 from repro.core.recovery import init_generator
 from repro.data.sensors import class_signatures, har_stream
 from repro.host import (CLUSTER_KIND, HostServeConfig, cluster_entries,
@@ -418,6 +418,35 @@ def test_fleet_layer_scope_in_op_metadata(fleet_hlo, name):
                for o in op_names), name
 
 
+@pytest.fixture(scope="module")
+def host_hlo(host_setup):
+    """The compiled host serve slot (telemetry on, two microbatches) on a
+    lane of both payload kinds."""
+    from repro.host import sampling_entries
+    from repro.host.server import _build_serve_slot
+    from repro.serving import encode_wire_samples
+    key, params, gen, wire = host_setup
+    cfg = _host_cfg(telemetry=True, batches_per_slot=2)
+    wins, _ = har_stream(key, 8)
+    sc = jax.vmap(lambda w, kk: importance_coreset(w, cfg.m, kk))(
+        wins, jax.random.split(key, 8))
+    swire = encode_wire_samples(sc.indices, sc.values, sc.mean, sc.var)
+    entries = jax.tree_util.tree_map(
+        lambda a, b: jnp.concatenate([a[:4], b[:4]]),
+        cluster_entries(wire, cfg.m), sampling_entries(swire, cfg.k))
+    run = _build_serve_slot(cfg, False)
+    return run.lower(host_server_init(cfg), entries,
+                     jnp.arange(8, dtype=jnp.int32), jnp.ones((8,), bool),
+                     params, gen, key).compile().as_text()
+
+
+@pytest.mark.parametrize("name", sorted(trace.HOST_SCOPES))
+def test_host_layer_scope_in_op_metadata(host_hlo, name):
+    op_names = re.findall(r'op_name="([^"]*)"', host_hlo)
+    assert any(re.search(rf"(?<![\w.]){re.escape(name)}(?![\w.])", o)
+               for o in op_names), name
+
+
 def test_scope_refuses_unregistered_name():
     with pytest.raises(ValueError, match="not registered"):
         trace.scope("seeker.unregistered")
@@ -432,7 +461,7 @@ def test_scope_names_in_use_are_registered():
     used = set()
     for path in (ROOT_DIR / "src" / "repro").rglob("*.py"):
         used |= set(re.findall(r'\bscope\("([^"]+)"\)', path.read_text()))
-    assert used == set(trace.SCOPES)
+    assert used == set(trace.SCOPES) | set(trace.HOST_SCOPES)
     named = set()
     for path in (ROOT_DIR / "bench").rglob("*.py"):
         named |= set(re.findall(r'"(seeker\.[A-Za-z_]+)"',
